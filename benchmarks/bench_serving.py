@@ -37,12 +37,12 @@ import numpy as np
 def _build_engine(max_batch=2, cache_len=64, **kw):
     from repro.configs import get
     from repro.core.plan import single_device_plan
-    from repro.runtime.steps import init_state
+    from repro.models.lm import LM
     from repro.serving import InferenceEngine
 
     cfg = get("ff-tiny").reduced()
     plan = single_device_plan()
-    params = init_state(cfg, plan, jax.random.PRNGKey(0))["params"]
+    params = LM(cfg).init(jax.random.PRNGKey(0))
     eng = InferenceEngine(cfg, plan, params, max_batch=max_batch,
                           cache_len=cache_len, **kw)
     return cfg, eng

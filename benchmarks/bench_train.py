@@ -12,6 +12,7 @@ import numpy as np
 
 from repro.configs import get
 from repro.core.plan import single_device_plan
+from repro.models.lm import LM
 from repro.runtime.steps import (init_state, make_decode_step,
                                  make_prefill_step, make_train_step)
 
@@ -39,7 +40,7 @@ def bench_train_step():
 def bench_decode_step():
     plan = single_device_plan()
     cfg = get("ff-tiny")
-    params = init_state(cfg, plan, jax.random.PRNGKey(0))["params"]
+    params = LM(cfg).init(jax.random.PRNGKey(0))
     B, S, CL = 8, 64, 128
     toks = jax.random.randint(jax.random.PRNGKey(1), (B, S), 0, cfg.vocab)
     _, caches = jax.jit(make_prefill_step(cfg, plan, CL))(

@@ -24,7 +24,7 @@ import numpy as np
 from repro.configs import get
 from repro.core import FF_EOS, JaxAccelerator
 from repro.core.plan import single_device_plan
-from repro.runtime.steps import init_state
+from repro.models.lm import LM
 from repro.serving import InferenceEngine, Request
 
 
@@ -55,7 +55,7 @@ def demo_serving():
     print("== inference engine: continuous batching ==")
     cfg = get("ff-tiny").reduced()
     plan = single_device_plan()
-    params = init_state(cfg, plan, jax.random.PRNGKey(0))["params"]
+    params = LM(cfg).init(jax.random.PRNGKey(0))
     rng = np.random.default_rng(0)
     with InferenceEngine(cfg, plan, params, max_batch=2,
                          cache_len=64) as eng:

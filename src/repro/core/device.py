@@ -28,13 +28,7 @@ from typing import Any, Callable, Optional, Sequence
 import jax
 import jax.numpy as jnp
 from jax import lax
-try:
-    from jax import shard_map as _shard_map_fn
-    def shard_map(f, mesh, in_specs, out_specs, check_rep=False):
-        return _shard_map_fn(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_rep)
-except ImportError:  # older jax
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 
@@ -56,7 +50,7 @@ def farm_map(fn: Callable, mesh: Mesh, axis: str = "data",
         return out
 
     return shard_map(worker, mesh=mesh, in_specs=in_specs,
-                     out_specs=out_specs, check_rep=False)
+                     out_specs=out_specs, check_vma=False)
 
 
 # ---------------------------------------------------------------------------
@@ -84,7 +78,7 @@ def tensor_map(fn: Callable, mesh: Mesh, axis: str = "model",
         spec[out_axis] = axis
         out_specs = P(*spec)
     return shard_map(worker, mesh=mesh, in_specs=split_spec,
-                     out_specs=out_specs, check_rep=False)
+                     out_specs=out_specs, check_vma=False)
 
 
 # ---------------------------------------------------------------------------
@@ -146,7 +140,7 @@ def pipeline_shard(stage_fn: Callable, mesh: Mesh, axis: str,
     def run(stage_params, x_mb):
         specs = jax.tree.map(lambda _: P(axis), stage_params)
         f = shard_map(body, mesh=mesh, in_specs=(specs, P()),
-                      out_specs=P(), check_rep=False)
+                      out_specs=P(), check_vma=False)
         return f(stage_params, x_mb)
 
     return run

@@ -18,7 +18,7 @@ import math
 import os
 import time
 import warnings
-from typing import Dict, Optional, Sequence
+from typing import Any, Dict, Optional, Sequence
 
 
 # --------------------------------------------------------------------------
@@ -98,7 +98,7 @@ def choose_microbatches(n_stages: int, max_bubble: float = 0.1,
 
 
 # --------------------------------------------------------------------------
-# TPU v5e roofline (target hardware; this container only dry-runs)
+# TPU v5e peaks per chip (Google Cloud documentation, "TPU v5e")
 # --------------------------------------------------------------------------
 @dataclasses.dataclass(frozen=True)
 class HardwareSpec:
@@ -250,6 +250,27 @@ DEFAULT_CALIBRATION = HostCalibration(
 # miss cleanly
 _CALIB_VERSION = 5
 _calibration: Optional[HostCalibration] = None
+
+
+def cache_key() -> Dict[str, Any]:
+    """What every cached record was measured on: the cache format version,
+    the host's core count and the JAX backend's first device.  A record
+    whose key differs is a miss, so constants measured on one backend (the
+    CPU, say) never steer placement or tiles on another (a TPU)."""
+    import jax
+    dev = jax.devices()[0]
+    return {"version": _CALIB_VERSION, "cpu_count": os.cpu_count(),
+            "platform": dev.platform, "device_kind": dev.device_kind}
+
+
+def _key_matches(d: Any, *, cpu: bool = True) -> bool:
+    """Does a loaded cache dict carry this process's :func:`cache_key`?
+    ``cpu=False`` ignores the core count (tile winners depend on the
+    accelerator backend and shape only)."""
+    if not isinstance(d, dict):
+        return False
+    return all(d.get(k) == v for k, v in cache_key().items()
+               if cpu or k != "cpu_count")
 
 
 def _calib_cache_path() -> str:
@@ -515,20 +536,17 @@ def _measure_net_hop(n: int = 200) -> float:
 
 
 def _measure_device_dispatch() -> float:
-    try:
-        import jax
-        import jax.numpy as jnp
-        f = jax.jit(lambda x: x + 1.0)
-        x = jnp.zeros((8,), jnp.float32)
-        jax.block_until_ready(f(x))             # compile outside the clock
-        best = float("inf")
-        for _ in range(5):
-            t0 = time.perf_counter()
-            jax.block_until_ready(f(x))
-            best = min(best, time.perf_counter() - t0)
-        return max(best, 1e-9)
-    except Exception:   # noqa: BLE001 - no usable backend: keep the default
-        return DEFAULT_CALIBRATION.device_dispatch_s
+    import jax
+    import jax.numpy as jnp
+    f = jax.jit(lambda x: x + 1.0)
+    x = jnp.zeros((8,), jnp.float32)
+    jax.block_until_ready(f(x))             # compile outside the clock
+    best = float("inf")
+    for _ in range(5):
+        t0 = time.perf_counter()
+        jax.block_until_ready(f(x))
+        best = min(best, time.perf_counter() - t0)
+    return max(best, 1e-9)
 
 
 def _measure_fused_segment(k: int = 4) -> float:
@@ -537,71 +555,62 @@ def _measure_fused_segment(k: int = 4) -> float:
     by ``k - 1``.  Near-zero on every real backend (XLA fuses the bodies) —
     which is the measured fact that lets ``place`` charge a fused run one
     dispatch instead of one per stage."""
-    try:
-        import jax
-        import jax.numpy as jnp
+    import jax
+    import jax.numpy as jnp
 
-        def _chain(n):
-            def f(x):
-                for i in range(n):
-                    x = x * 1.0001 + float(i)
-                return x
-            return jax.jit(f)
+    def _chain(n):
+        def f(x):
+            for i in range(n):
+                x = x * 1.0001 + float(i)
+            return x
+        return jax.jit(f)
 
-        x = jnp.zeros((8,), jnp.float32)
+    x = jnp.zeros((8,), jnp.float32)
 
-        def _best(f):
-            jax.block_until_ready(f(x))         # compile outside the clock
-            best = float("inf")
-            for _ in range(5):
-                t0 = time.perf_counter()
-                jax.block_until_ready(f(x))
-                best = min(best, time.perf_counter() - t0)
-            return best
+    def _best(f):
+        jax.block_until_ready(f(x))         # compile outside the clock
+        best = float("inf")
+        for _ in range(5):
+            t0 = time.perf_counter()
+            jax.block_until_ready(f(x))
+            best = min(best, time.perf_counter() - t0)
+        return best
 
-        t1, tk = _best(_chain(1)), _best(_chain(k))
-        return max((tk - t1) / (k - 1), 1e-9)
-    except Exception:   # noqa: BLE001 - no usable backend: keep the default
-        return DEFAULT_CALIBRATION.fused_segment_s
+    t1, tk = _best(_chain(1)), _best(_chain(k))
+    return max((tk - t1) / (k - 1), 1e-9)
 
 
 def _measure_h2d_bw(nbytes: int = 4 << 20, reps: int = 5) -> float:
     """Host->device boundary bandwidth (GB/s): one device_put of an
     ``nbytes`` float32 array, synced, best of ``reps`` — the per-microbatch
     input cost of the device boundary node."""
-    try:
-        import jax
-        import numpy as np
-        a = np.zeros(nbytes // 4, dtype=np.float32)
-        jax.block_until_ready(jax.device_put(a))    # warm the path
-        best = float("inf")
-        for _ in range(reps):
-            t0 = time.perf_counter()
-            jax.block_until_ready(jax.device_put(a))
-            best = min(best, time.perf_counter() - t0)
-        return max(nbytes / max(best, 1e-9) / 1e9, 1e-3)
-    except Exception:   # noqa: BLE001 - no usable backend: keep the default
-        return DEFAULT_CALIBRATION.h2d_bw_gbs
+    import jax
+    import numpy as np
+    a = np.zeros(nbytes // 4, dtype=np.float32)
+    jax.block_until_ready(jax.device_put(a))    # warm the path
+    best = float("inf")
+    for _ in range(reps):
+        t0 = time.perf_counter()
+        jax.block_until_ready(jax.device_put(a))
+        best = min(best, time.perf_counter() - t0)
+    return max(nbytes / max(best, 1e-9) / 1e9, 1e-3)
 
 
 def _measure_d2h_bw(nbytes: int = 4 << 20, reps: int = 5) -> float:
     """Device->host boundary bandwidth (GB/s): one full host copy-out of an
     ``nbytes`` device array, best of ``reps`` — the per-microbatch output
     cost of the device boundary node."""
-    try:
-        import jax
-        import numpy as np
-        x = jax.block_until_ready(
-            jax.device_put(np.zeros(nbytes // 4, dtype=np.float32)))
-        np.asarray(x)                               # warm the path
-        best = float("inf")
-        for _ in range(reps):
-            t0 = time.perf_counter()
-            np.asarray(x)
-            best = min(best, time.perf_counter() - t0)
-        return max(nbytes / max(best, 1e-9) / 1e9, 1e-3)
-    except Exception:   # noqa: BLE001 - no usable backend: keep the default
-        return DEFAULT_CALIBRATION.d2h_bw_gbs
+    import jax
+    import numpy as np
+    x = jax.block_until_ready(
+        jax.device_put(np.zeros(nbytes // 4, dtype=np.float32)))
+    np.asarray(x)                               # warm the path
+    best = float("inf")
+    for _ in range(reps):
+        t0 = time.perf_counter()
+        np.asarray(x)
+        best = min(best, time.perf_counter() - t0)
+    return max(nbytes / max(best, 1e-9) / 1e9, 1e-3)
 
 
 def _measure_overlap_eff(k: int = 8, reps: int = 3) -> float:
@@ -612,27 +621,24 @@ def _measure_overlap_eff(k: int = 8, reps: int = 3) -> float:
     trips the window hides; clamped to [0, 1].  A backend with synchronous
     dispatch measures ~0 and place() falls back to costing the boundary as
     transfer + compute."""
-    try:
-        import jax
-        import jax.numpy as jnp
-        f = jax.jit(lambda x: x * 1.0001 + 1.0)
-        x = jnp.zeros((256, 256), jnp.float32)
-        jax.block_until_ready(f(x))                 # compile off the clock
-        serial = window = float("inf")
-        for _ in range(reps):
-            t0 = time.perf_counter()
-            for _i in range(k):
-                jax.block_until_ready(f(x))
-            serial = min(serial, time.perf_counter() - t0)
-            t0 = time.perf_counter()
-            ys = [f(x) for _i in range(k)]
-            jax.block_until_ready(ys)
-            window = min(window, time.perf_counter() - t0)
-        if serial <= 0.0 or not (serial < float("inf")):
-            return DEFAULT_CALIBRATION.overlap_eff
-        return min(1.0, max(0.0, 1.0 - window / serial))
-    except Exception:   # noqa: BLE001 - no usable backend: keep the default
+    import jax
+    import jax.numpy as jnp
+    f = jax.jit(lambda x: x * 1.0001 + 1.0)
+    x = jnp.zeros((256, 256), jnp.float32)
+    jax.block_until_ready(f(x))                 # compile off the clock
+    serial = window = float("inf")
+    for _ in range(reps):
+        t0 = time.perf_counter()
+        for _i in range(k):
+            jax.block_until_ready(f(x))
+        serial = min(serial, time.perf_counter() - t0)
+        t0 = time.perf_counter()
+        ys = [f(x) for _i in range(k)]
+        jax.block_until_ready(ys)
+        window = min(window, time.perf_counter() - t0)
+    if serial <= 0.0 or not (serial < float("inf")):
         return DEFAULT_CALIBRATION.overlap_eff
+    return min(1.0, max(0.0, 1.0 - window / serial))
 
 
 def calibrate(cache: bool = True) -> HostCalibration:
@@ -646,7 +652,8 @@ def calibrate(cache: bool = True) -> HostCalibration:
 
     A read-only or unwritable cache location (containerized remote workers,
     sealed CI sandboxes) degrades to in-memory constants with a one-line
-    warning — never an exception."""
+    warning — never an exception.  A device probe that fails raises: a
+    broken backend must not hide behind the baked-in defaults."""
     global _calibration
     c = HostCalibration(
         peak_flops=_measure_peak_flops(),
@@ -667,8 +674,7 @@ def calibrate(cache: bool = True) -> HostCalibration:
         try:
             os.makedirs(os.path.dirname(path), exist_ok=True)
             with open(path, "w") as f:
-                json.dump({"version": _CALIB_VERSION,
-                           "cpu_count": os.cpu_count(), **c.as_dict()}, f)
+                json.dump({**cache_key(), **c.as_dict()}, f)
         except OSError as e:
             warnings.warn(
                 f"perf_model: calibration cache {path!r} is not writable "
@@ -681,9 +687,7 @@ def _load_cached_calibration() -> Optional[HostCalibration]:
     try:
         with open(_calib_cache_path()) as f:
             d = json.load(f)
-        if not isinstance(d, dict) \
-                or d.get("version") != _CALIB_VERSION \
-                or d.get("cpu_count") != os.cpu_count():
+        if not _key_matches(d):
             return None
         return HostCalibration(
             peak_flops=float(d["peak_flops"]),
@@ -761,8 +765,7 @@ def _load_observed() -> Dict[str, dict]:
             with open(_calib_cache_path()) as f:
                 d = json.load(f)
             obs = d.get("observed")
-            if (isinstance(obs, dict) and d.get("version") == _CALIB_VERSION
-                    and d.get("cpu_count") == os.cpu_count()):
+            if isinstance(obs, dict) and _key_matches(d):
                 _observed = {str(k): dict(v) for k, v in obs.items()
                              if isinstance(v, dict)}
         except (OSError, ValueError, TypeError):
@@ -799,8 +802,7 @@ def _save_cache_tables(what: str = "observed costs") -> None:
     try:
         os.makedirs(os.path.dirname(path), exist_ok=True)
         with open(path, "w") as f:
-            json.dump({"version": _CALIB_VERSION,
-                       "cpu_count": os.cpu_count(), **c.as_dict(),
+            json.dump({**cache_key(), **c.as_dict(),
                        "observed": _load_observed(),
                        "autotune": _load_autotune()}, f)
     except OSError as e:
@@ -840,7 +842,7 @@ def _load_autotune() -> Dict[str, dict]:
             at = d.get("autotune")
             # unlike the observed table, tile winners do not gate on
             # cpu_count: they depend on the accelerator backend and shape
-            if isinstance(at, dict) and d.get("version") == _CALIB_VERSION:
+            if isinstance(at, dict) and _key_matches(d, cpu=False):
                 _autotune = {str(k): dict(v) for k, v in at.items()
                              if isinstance(v, dict)}
         except (OSError, ValueError, TypeError):

@@ -76,7 +76,10 @@ def _mp_context():
 def _quiet_fork():
     # jax warns on any fork from a multithreaded process; our children never
     # touch jax (they run pure-python/numpy svc callables), so the warning
-    # is noise here
+    # is noise here.  On a TPU v5e host a fork after the parent holds the
+    # chip works on that condition: process farms, the calibration echo
+    # children and loopback worker pools all ran there.  A child that
+    # touched jax would need the chip, which one process holds at a time.
     with warnings.catch_warnings():
         warnings.filterwarnings("ignore", message=r"os\.fork\(\) was called",
                                 category=RuntimeWarning)

@@ -60,7 +60,7 @@ def _kernel(logits_ref, xs_ref, out_ref, keep_ref, counts_ref, *,
 
     # -- capacity position: running VMEM lane cursors + rank in this block --
     onehot = jax.nn.one_hot(idx, E, dtype=jnp.int32)      # (bt, E)
-    within = jnp.cumsum(onehot, axis=0) - onehot          # exclusive rank
+    within = exclusive_rank(onehot)                       # (bt, E)
     base = counts_ref[...]                                # (E,)
     pos = jnp.sum((within + base[None, :]) * onehot, axis=-1)
     keep = pos < capacity                                 # (bt,)
@@ -78,9 +78,25 @@ def _kernel(logits_ref, xs_ref, out_ref, keep_ref, counts_ref, *,
     counts_ref[...] = base + jnp.sum(onehot, axis=0)
 
 
+def exclusive_rank(onehot):
+    """Per-column exclusive running count of a ``(bt, E)`` 0/1 matrix: row
+    ``t`` holds how many earlier rows share each column.  Mosaic has no
+    ``cumsum`` lowering, so this is one MXU matmul with the strictly lower
+    triangular ones matrix; 0/1 inputs are exact in bf16 and the f32
+    accumulator is exact for any block under 2**24 rows."""
+    bt = onehot.shape[0]
+    row = jax.lax.broadcasted_iota(jnp.int32, (bt, bt), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (bt, bt), 1)
+    tril = (col < row).astype(jnp.bfloat16)
+    return jax.lax.dot_general(
+        tril, onehot.astype(jnp.bfloat16), (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32).astype(jnp.int32)
+
+
 def _pick_block(T: int, block_t: Optional[int], E: int, Din: int) -> int:
     """Requested block, else the autotuned winner for this shape, else a
-    heuristic — always snapped down to a divisor of T."""
+    heuristic — snapped down to a multiple of 8 that divides T (the TPU's
+    sublane tiling), or T itself when no such divisor fits."""
     if block_t is None:
         try:  # lazy: kernels must stay importable without the core package
             from ..core import perf_model as pm
@@ -91,10 +107,10 @@ def _pick_block(T: int, block_t: Optional[int], E: int, Din: int) -> int:
             block_t = None
     if block_t is None:
         block_t = 128
-    bt = max(1, min(block_t, T))
-    while T % bt:
-        bt -= 1
-    return bt
+    bt = min(block_t, T) // 8 * 8
+    while bt and T % bt:
+        bt -= 8
+    return bt or T
 
 
 def a2a_fused(logits, xs, expert_fns: Sequence[Callable], capacity: int, *,

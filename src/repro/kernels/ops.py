@@ -1,11 +1,12 @@
 """jit'd public wrappers for the Pallas kernels, with XLA fallbacks and
 recompute-from-oracle backward passes.
 
-On this CPU container the kernels run under ``interpret=True`` (the kernel
-body executes in Python) — correctness validation only.  On TPU the same
-``pl.pallas_call`` lowers to Mosaic.  ``custom_vjp`` backward recomputes
-through the ref oracle (forward-optimized; dedicated bwd kernels are listed
-as future perf headroom in EXPERIMENTS.md §Perf)."""
+Off a TPU the kernels run under ``interpret=True`` (the kernel body
+executes in Python), which checks results only.  On a TPU the same
+``pl.pallas_call`` lowers to Mosaic; ``tests/test_tpu_compile.py`` compiles
+each kernel for a described v5e.  ``custom_vjp`` backward recomputes
+through the ref oracle (forward-optimized; there are no backward
+kernels)."""
 
 from __future__ import annotations
 

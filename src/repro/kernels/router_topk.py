@@ -18,6 +18,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .a2a_fused import exclusive_rank
 from .backend import default_interpret
 
 NEG_INF = -1.0e38
@@ -34,34 +35,40 @@ def _kernel(logits_ref, w_ref, idx_ref, pos_ref, keep_ref, counts_ref, *,
     logits = logits_ref[...].astype(jnp.float32)          # (bt, E)
     probs = jax.nn.softmax(logits, axis=-1)
 
-    # iterative top-k (K small)
+    # iterative top-k (K small); the (bt, K) outputs are assembled by lane
+    # select rather than stack/reshape, which Mosaic does not lower here
+    lane = jax.lax.broadcasted_iota(jnp.int32, (bt, K), 1)
     masked = probs
-    ws, idxs = [], []
-    for _ in range(K):
-        w = jnp.max(masked, axis=-1)
-        i = jnp.argmax(masked, axis=-1)
-        ws.append(w)
-        idxs.append(i)
-        masked = jnp.where(jax.nn.one_hot(i, E, dtype=jnp.bool_),
-                           NEG_INF, masked)
-    w = jnp.stack(ws, axis=-1)                            # (bt, K)
-    idx = jnp.stack(idxs, axis=-1).astype(jnp.int32)      # (bt, K)
+    w = jnp.zeros((bt, K), jnp.float32)
+    idx = jnp.zeros((bt, K), jnp.int32)
+    hots = []
+    for k in range(K):
+        wk = jnp.max(masked, axis=-1)
+        ik = jnp.argmax(masked, axis=-1).astype(jnp.int32)
+        w = jnp.where(lane == k, wk[:, None], w)
+        idx = jnp.where(lane == k, ik[:, None], idx)
+        hot = jax.nn.one_hot(ik, E, dtype=jnp.int32)      # (bt, E)
+        hots.append(hot)
+        masked = jnp.where(hot > 0, NEG_INF, masked)
     w = w / jnp.maximum(jnp.sum(w, axis=-1, keepdims=True), 1e-9)
 
-    # positions: running expert counters + rank within this block
-    flat = idx.reshape(bt * K)
-    onehot = jax.nn.one_hot(flat, E, dtype=jnp.int32)     # (bt*K, E)
-    within = jnp.cumsum(onehot, axis=0) - onehot          # exclusive rank
+    # positions in (token, k) order: the running expert counters, plus every
+    # slot of earlier tokens in this block, plus this token's earlier slots
+    total = sum(hots)                                     # (bt, E)
+    earlier = exclusive_rank(total)                       # (bt, E)
     base = counts_ref[...]                                # (E,)
-    pos = (within + base[None, :])                        # (bt*K, E)
-    pos = jnp.sum(pos * onehot, axis=-1)                  # (bt*K,)
-    keep = pos < capacity
+    pos = jnp.zeros((bt, K), jnp.int32)
+    own = jnp.zeros((bt, E), jnp.int32)
+    for k, hot in enumerate(hots):
+        pk = jnp.sum((earlier + own + base[None, :]) * hot, axis=-1)
+        pos = jnp.where(lane == k, pk[:, None], pos)
+        own = own + hot
 
     w_ref[...] = w.astype(w_ref.dtype)
     idx_ref[...] = idx
-    pos_ref[...] = pos.reshape(bt, K).astype(jnp.int32)
-    keep_ref[...] = keep.reshape(bt, K)
-    counts_ref[...] = base + jnp.sum(onehot, axis=0)
+    pos_ref[...] = pos
+    keep_ref[...] = pos < capacity
+    counts_ref[...] = base + jnp.sum(total, axis=0)
 
 
 def router_topk(logits, top_k: int, capacity: int, *, block_t: int = 256,

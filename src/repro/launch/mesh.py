@@ -11,11 +11,7 @@ import jax
 
 
 def _mesh_kwargs(axes):
-    # jax < 0.5 has no sharding.AxisType; Auto is its only behaviour anyway
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return {}
-    return {"axis_types": (axis_type.Auto,) * len(axes)}
+    return {"axis_types": (jax.sharding.AxisType.Auto,) * len(axes)}
 
 
 def make_production_mesh(*, multi_pod: bool = False):

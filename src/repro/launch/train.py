@@ -2,15 +2,20 @@
 
     PYTHONPATH=src python -m repro.launch.train --arch ff-tiny --steps 100
     PYTHONPATH=src python -m repro.launch.train --arch gemma-7b --reduced
+    PYTHONPATH=src python -m repro.launch.train --arch xlstm-125m \
+        --batch 8 --seq 2048 --steps 20
 
-On a real TPU fleet this process runs per-host under jax.distributed; on
-this container it drives the single CPU device through the same code path.
+One process drives every local device: a data-parallel mesh with FSDP
+parameter sharding over all of them, or the single device when there is
+one.  Configs run at their published widths unless ``--reduced`` is given.
 """
 
 from __future__ import annotations
 
 import argparse
 import json
+import os
+import tempfile
 
 import jax
 
@@ -19,8 +24,42 @@ from ..core.plan import ShardingPlan, single_device_plan
 from ..data import SyntheticLMSource, make_pipeline
 from ..optim.schedules import cosine_warmup
 from ..runtime.driver import DriverConfig, TrainDriver
-from ..runtime.steps import init_state, make_train_step
+from ..runtime.steps import init_state, make_train_step, state_shardings
+from .compile_cache import enable_compile_cache
 from .mesh import make_host_mesh
+
+
+def local_plan(n_devices: int) -> ShardingPlan:
+    """Data-parallel FSDP plan over the first ``n_devices`` local devices
+    (the single-device plan when that is one)."""
+    if n_devices > 1:
+        return ShardingPlan(mesh=make_host_mesh(data=n_devices))
+    return single_device_plan()
+
+
+def train(cfg, plan: ShardingPlan, *, steps: int, batch: int, seq: int,
+          lr: float, ckpt_dir: str, ckpt_every: int, adaptive: bool = False,
+          seed: int = 0):
+    """Train ``cfg`` for ``steps`` steps on synthetic tokens made from
+    ``seed``: the data pipeline feeds the fault-tolerant driver, which runs
+    the jitted step with the train state donated (learning rate warmed up
+    over the first fifth of the run, at most 20 steps).  Returns the
+    driver's result (``history`` holds the per-step losses), the data
+    pipeline and the final train state."""
+    state = jax.device_put(init_state(cfg, plan, jax.random.PRNGKey(seed)),
+                           state_shardings(cfg, plan))
+    src = SyntheticLMSource(cfg.vocab, seq, batch, seed=seed)
+    pipe = make_pipeline(src, plan, n_batches=steps + 8, adaptive=adaptive)
+    warmup = min(20, max(1, steps // 5))
+    step = jax.jit(make_train_step(cfg, plan, cosine_warmup(lr, warmup,
+                                                            steps)),
+                   donate_argnums=0)
+    driver = TrainDriver(step, state, pipe,
+                         DriverConfig(total_steps=steps,
+                                      ckpt_every=ckpt_every,
+                                      ckpt_dir=ckpt_dir))
+    out = driver.run()
+    return out, pipe, driver.state
 
 
 def main():
@@ -32,7 +71,9 @@ def main():
     ap.add_argument("--lr", type=float, default=3e-3)
     ap.add_argument("--reduced", action="store_true",
                     help="CPU-sized reduction of the arch")
-    ap.add_argument("--ckpt-dir", default="/tmp/repro_train_ckpt")
+    ap.add_argument("--ckpt-dir",
+                    default=os.path.join(tempfile.gettempdir(),
+                                         "repro_train_ckpt"))
     ap.add_argument("--ckpt-every", type=int, default=50)
     ap.add_argument("--adaptive", action="store_true",
                     help="adaptive data pipeline: a runtime Supervisor "
@@ -47,30 +88,21 @@ def main():
         from .tuned import apply_tuned
         apply_tuned()
 
+    enable_compile_cache()
     cfg = get(args.arch)
-    if args.reduced or args.arch != "ff-tiny":
+    if args.reduced:
         cfg = cfg.reduced()
     n_dev = len(jax.devices())
-    plan = ShardingPlan(mesh=make_host_mesh(data=n_dev)) if n_dev > 1 \
-        else single_device_plan()
-
-    state = init_state(cfg, plan, jax.random.PRNGKey(0))
-    n_params = sum(p.size for p in jax.tree.leaves(state["params"]))
-    print(f"arch={cfg.name} params={n_params/1e6:.2f}M devices={n_dev}")
-
-    src = SyntheticLMSource(cfg.vocab, args.seq, args.batch, seed=0)
-    pipe = make_pipeline(src, plan, n_batches=args.steps + 8,
+    plan = local_plan(n_dev)
+    print(f"arch={cfg.name} params={cfg.n_params()/1e6:.2f}M "
+          f"devices={n_dev}")
+    out, pipe, _ = train(cfg, plan, steps=args.steps, batch=args.batch,
+                         seq=args.seq, lr=args.lr, ckpt_dir=args.ckpt_dir,
+                         ckpt_every=args.ckpt_every,
                          adaptive=args.adaptive)
     print(f"data graph: {pipe.graph.describe()}")
     for desc, p in pipe.placements:
         print(f"  [{p.target:6s}] {desc}")
-    step = jax.jit(make_train_step(
-        cfg, plan, cosine_warmup(args.lr, 20, args.steps)), donate_argnums=0)
-    driver = TrainDriver(step, state, pipe,
-                         DriverConfig(total_steps=args.steps,
-                                      ckpt_every=args.ckpt_every,
-                                      ckpt_dir=args.ckpt_dir, log_every=10))
-    out = driver.run()
     losses = [h["loss"] for h in out["history"]]
     print(f"final step {out['final_step']}: loss {losses[0]:.3f} -> "
           f"{losses[-1]:.3f}; restarts={out['restarts']} "

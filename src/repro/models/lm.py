@@ -29,13 +29,7 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 from jax import lax
-try:
-    from jax import shard_map as _shard_map_fn
-    def shard_map(f, mesh, in_specs, out_specs, check_rep=False):
-        return _shard_map_fn(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_rep)
-except ImportError:  # older jax
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from .attention import attention, attn_defs, cross_attention, cross_kv
@@ -75,7 +69,7 @@ def vocab_parallel_embed(tokens, emb, plan):
     out_spec = P(b_ax, m_ax if seq_scatter else None, None)
     return shard_map(body, mesh=mesh,
                      in_specs=(P(b_ax, None), P("model", None)),
-                     out_specs=out_spec, check_rep=False)(tokens, emb)
+                     out_specs=out_spec, check_vma=False)(tokens, emb)
 
 
 def vocab_parallel_ce(x, unemb, labels, mask, plan, chunks: int = 1):
@@ -130,7 +124,7 @@ def vocab_parallel_ce(x, unemb, labels, mask, plan, chunks: int = 1):
         body, mesh=mesh,
         in_specs=(P(b_ax, x_seq_ax, None), P(None, "model"),
                   P(b_ax, None), P(b_ax, None)),
-        out_specs=(P(), P()), check_rep=False)(x, unemb, labels, mask)
+        out_specs=(P(), P()), check_vma=False)(x, unemb, labels, mask)
     return loss / jnp.maximum(cnt, 1.0)
 
 

@@ -25,13 +25,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax import lax
-try:
-    from jax import shard_map as _shard_map_fn
-    def shard_map(f, mesh, in_specs, out_specs, check_rep=False):
-        return _shard_map_fn(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_rep)
-except ImportError:  # older jax
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from ..core.device import expert_capacity
@@ -190,7 +184,7 @@ def moe_block(x, p, cfg, plan):
     router = plan.gather_fsdp(p["router"], ("fsdp", None))
     fn = shard_map(body, mesh=mesh,
                    in_specs=in_specs,
-                   out_specs=(xspec, P(), P()), check_rep=False)
+                   out_specs=(xspec, P(), P()), check_vma=False)
     out, lb, z = fn(x, router, wi, wg, wo)
 
     if cfg.n_shared_experts:

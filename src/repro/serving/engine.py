@@ -109,14 +109,31 @@ class Overloaded:
     backlog: int = 0
 
 
+@dataclasses.dataclass
+class _Failed:
+    """A request whose prefill raised: its handle re-raises ``error``."""
+
+    request: Request
+    error: BaseException
+
+
+def _outcome(item: Any) -> Union[Request, Overloaded]:
+    """A delivered outcome as the client sees it: a failed request raises
+    the error that failed it (a device or compiler fault is not overload)."""
+    if isinstance(item, _Failed):
+        raise item.error
+    return item
+
+
 class RequestHandle:
     """Future for one submitted request: resolves to the finished
-    :class:`Request` or a typed :class:`Overloaded`."""
+    :class:`Request` or a typed :class:`Overloaded`; ``result()`` raises the
+    exception that failed the request's prefill."""
 
     def __init__(self, request: Request):
         self.request = request
         self._event = threading.Event()
-        self._outcome: Union[Request, Overloaded, None] = None
+        self._outcome: Union[Request, Overloaded, _Failed, None] = None
 
     def done(self) -> bool:
         return self._event.is_set()
@@ -126,9 +143,9 @@ class RequestHandle:
         if not self._event.wait(timeout):
             raise TimeoutError(
                 f"request {self.request.id} not finished in {timeout}s")
-        return self._outcome
+        return _outcome(self._outcome)
 
-    def _resolve(self, outcome: Union[Request, Overloaded]) -> None:
+    def _resolve(self, outcome: Union[Request, Overloaded, _Failed]) -> None:
         self._outcome = outcome
         self._event.set()
 
@@ -144,6 +161,7 @@ class _Accounting:
         self.admitted = 0       # inserted into a batch slot
         self.finished = 0
         self.shed = 0
+        self.failed = 0         # prefill raised: never admitted
 
     def bump(self, field: str) -> None:
         with self._lock:
@@ -153,12 +171,12 @@ class _Accounting:
         """Requests accepted but not yet decoding (input queue + prefill +
         ready queue) — what admission back-pressure bounds."""
         with self._lock:
-            return self.submitted - self.shed - self.admitted
+            return self.submitted - self.shed - self.failed - self.admitted
 
     def in_flight(self) -> int:
         """Requests with an outcome still owed (anywhere in the engine)."""
         with self._lock:
-            return self.submitted - self.shed - self.finished
+            return self.submitted - self.shed - self.failed - self.finished
 
 
 class _SLOState:
@@ -246,7 +264,7 @@ class PrefillNode(FFNode):
                 out = _Ready(req, tok, cache1, int(prompt.shape[1]))
                 with self._stats_lock:
                     self.prefills += 1
-            except BaseException as e:  # noqa: BLE001 - surfaced as a shed
+            except Exception as e:  # noqa: BLE001 - re-raised by the handle
                 out = _Ready(req, error=e)
             self._emit(out)
 
@@ -348,7 +366,8 @@ class CacheManager(FFNode):
             r = self.ready.popleft()
             req = r.req
             if r.error is not None:
-                self._shed(req, f"prefill failed: {r.error!r}")
+                self.acct.bump("failed")
+                self.ff_send_out(Deliver(_Failed(req, r.error)))
                 continue
             if (req.deadline_s is not None
                     and now - req.submit_t > req.deadline_s):
@@ -563,9 +582,13 @@ class InferenceEngine:
         self._prefill_node = PrefillNode(jax.jit(_prefill), params,
                                          n_workers=prefill_workers)
         self._cm = CacheManager(self.state, self.B,
-                                jax.jit(self._insert_impl), self._acct,
+                                jax.jit(self._insert_impl,
+                                        donate_argnums=0), self._acct,
                                 self._slo, max_pending)
-        self._decode_node = DecodeNode(self.state, params, jax.jit(_decode))
+        # the batched caches are donated to the insert and decode steps: the
+        # chip holds one copy of them, updated in place
+        self._decode_node = DecodeNode(self.state, params,
+                                       jax.jit(_decode, donate_argnums=1))
         self._collect = CollectNode(self.state, self._cm, self._acct,
                                     self._slo, eos_token, exit_threshold)
 
@@ -618,7 +641,8 @@ class InferenceEngine:
         s["requests"] = {"submitted": self._acct.submitted,
                          "admitted": self._acct.admitted,
                          "finished": self._acct.finished,
-                         "shed": self._acct.shed}
+                         "shed": self._acct.shed,
+                         "failed": self._acct.failed}
         if self.supervisor is not None:
             s["supervisor"] = self.supervisor.stats()
         return s
@@ -678,7 +702,7 @@ class InferenceEngine:
             if not ok:                    # network EOS: loop fully drained
                 self._results_q.put(_END)
                 return
-            rid = (item.request.id if isinstance(item, Overloaded)
+            rid = (item.request.id if isinstance(item, (Overloaded, _Failed))
                    else item.id)
             with self._handles_lock:
                 h = self._handles.pop(rid, None)
@@ -725,13 +749,14 @@ class InferenceEngine:
 
     def results(self) -> Iterator[Union[Request, Overloaded]]:
         """Iterate every outcome (finished ``Request`` or ``Overloaded``)
-        in completion order, until the engine is drained."""
+        in completion order, until the engine is drained.  A request whose
+        prefill failed raises its error here."""
         while True:
             item = self._results_q.get()
             if item is _END:
                 self._results_q.put(_END)   # repeated iteration stays ended
                 return
-            yield item
+            yield _outcome(item)
 
     def close(self, timeout: Optional[float] = 60.0) -> int:
         """Stop accepting, drain in-flight requests, shut the network,
@@ -778,7 +803,7 @@ class InferenceEngine:
         if item is _END:
             self._results_q.put(_END)
             return False, None
-        return True, item
+        return True, _outcome(item)
 
     def load_result_nb(self):
         try:
@@ -788,7 +813,7 @@ class InferenceEngine:
         if item is _END:
             self._results_q.put(_END)
             return False, None
-        return True, item
+        return True, _outcome(item)
 
     def wait(self, timeout: Optional[float] = None) -> int:
         """Join the drained network.  The terminating EOS originates

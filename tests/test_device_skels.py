@@ -54,7 +54,7 @@ _SUBPROC = textwrap.dedent("""
     from jax.sharding import PartitionSpec as P
     from repro.launch.mesh import make_mesh
     from repro.core.device import pipeline_shard, flash_decode_combine
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
 
     mesh = make_mesh((4, 2), ("stage", "model"))
 
@@ -94,7 +94,7 @@ _SUBPROC = textwrap.dedent("""
     f = shard_map(local_attn, mesh=mesh,
                   in_specs=(P(), P(None, "model", None, None),
                             P(None, "model", None, None)),
-                  out_specs=P(), check_rep=False)
+                  out_specs=P(), check_vma=False)
     got2 = f(q, k, v)
     s = jnp.einsum("bhd,bkhd->bhk", q, k) / jnp.sqrt(D)
     p = jax.nn.softmax(s, -1)

@@ -288,6 +288,17 @@ def test_a2a_fused_scalar_output_experts(rng):
 
 
 @pytest.mark.kernels
+@pytest.mark.parametrize("T,block_t,want", [(200, 128, 40), (4096, 128, 128),
+                                            (12, 128, 12), (36, 32, 36),
+                                            (7, 4, 7)])
+def test_a2a_fused_block_is_sublane_tiled(T, block_t, want):
+    """A token block the TPU accepts: a multiple of 8 that divides T, or T
+    itself (T=200 used to give 100)."""
+    from repro.kernels.a2a_fused import _pick_block
+    assert _pick_block(T, block_t, 2, 8) == want
+
+
+@pytest.mark.kernels
 def test_a2a_fused_rejects_mismatched_experts(rng):
     import jax
     import jax.numpy as jnp

@@ -10,8 +10,8 @@ import pytest
 
 from repro.configs import get
 from repro.core import FF_EOS
-from repro.runtime.steps import (init_state, make_decode_step,
-                                 make_prefill_step)
+from repro.models.lm import LM
+from repro.runtime.steps import make_decode_step, make_prefill_step
 from repro.serving import InferenceEngine, Overloaded, Request
 
 pytestmark = pytest.mark.serving
@@ -22,7 +22,7 @@ def served(plan_module=None):
     from repro.core.plan import single_device_plan
     plan = single_device_plan()
     cfg = get("ff-tiny").reduced()
-    params = init_state(cfg, plan, jax.random.PRNGKey(0))["params"]
+    params = LM(cfg).init(jax.random.PRNGKey(0))
     return cfg, plan, params
 
 
@@ -301,3 +301,38 @@ def test_cache_manager_stats_surface(served):
     # pushing a pressure level through the handle reaches admission
     cm.set_pressure(2)
     assert eng._slo.ext_level == 2
+
+
+def test_prefill_error_reaches_handle_as_that_exception(served):
+    """A prefill that raises fails its own request with that exception (a
+    compiler or device fault is not overload); the engine keeps serving and
+    still drains."""
+    cfg, plan, params = served
+    rng = np.random.default_rng(13)
+
+    class PrefillFault(RuntimeError):
+        pass
+
+    eng = InferenceEngine(cfg, plan, params, max_batch=2, cache_len=64)
+    real = eng._prefill_node._prefill
+
+    def flaky(p, prompt):
+        if prompt.shape[1] == 5:
+            raise PrefillFault("refused")
+        return real(p, prompt)
+
+    eng._prefill_node._prefill = flaky
+    with eng:
+        bad = eng.submit(Request(
+            prompt=rng.integers(0, cfg.vocab, 5, dtype=np.int32),
+            max_new_tokens=3))
+        good = eng.submit(Request(
+            prompt=rng.integers(0, cfg.vocab, 6, dtype=np.int32),
+            max_new_tokens=3))
+        with pytest.raises(PrefillFault, match="refused"):
+            bad.result(timeout=300)
+        assert len(good.result(timeout=300).tokens) == 3
+    assert eng.shed_count == 0
+    assert eng.stats()["requests"]["failed"] == 1
+    with pytest.raises(PrefillFault):
+        list(eng.results())

@@ -382,15 +382,42 @@ def test_calibration_cache_roundtrips_batched_constants(tmp_path, monkeypatch):
                             shm_batched_hop_s=7e-6, arena_bw_gbs=3.5,
                             source="measured")
     with open(tmp_path / "calib.json", "w") as f:
-        json.dump({"version": pm._CALIB_VERSION,
-                   "cpu_count": os.cpu_count(), **c.as_dict()}, f)
+        json.dump({**pm.cache_key(), **c.as_dict()}, f)
     loaded = pm._load_cached_calibration()
     assert loaded is not None
     assert loaded.shm_batched_hop_s == 7e-6
     assert loaded.arena_bw_gbs == 3.5
+    # constants measured on another backend's device must miss
+    with open(tmp_path / "calib.json", "w") as f:
+        json.dump({**pm.cache_key(), **c.as_dict(),
+                   "platform": "tpu", "device_kind": "TPU v5 lite"}, f)
+    assert pm._load_cached_calibration() is None
     # version-2 caches (no batched constants) must miss cleanly
     with open(tmp_path / "calib.json", "w") as f:
         d = {"version": 2, "cpu_count": os.cpu_count(), **c.as_dict()}
         del d["shm_batched_hop_s"], d["arena_bw_gbs"]
         json.dump(d, f)
     assert pm._load_cached_calibration() is None
+
+
+def test_autotune_records_are_keyed_by_device(tmp_path, monkeypatch):
+    """Tile winners persist with the backend device in their key: a table
+    tuned on another device is a miss, never a tile to use here."""
+    import json
+    monkeypatch.setenv("REPRO_FF_CALIB_CACHE", str(tmp_path / "calib.json"))
+    pm.reset_autotuned()
+    try:
+        assert pm.record_autotuned({"a2a_fused:T64:E2:D8": {"block_t": 16}})
+        pm.reset_autotuned()
+        assert pm.lookup_autotuned("a2a_fused:T64:E2:D8") == {"block_t": 16}
+        with open(tmp_path / "calib.json") as f:
+            d = json.load(f)
+        assert {k: d[k] for k in ("platform", "device_kind")} == {
+            k: pm.cache_key()[k] for k in ("platform", "device_kind")}
+        d["device_kind"] = "another device"
+        with open(tmp_path / "calib.json", "w") as f:
+            json.dump(d, f)
+        pm.reset_autotuned()
+        assert pm.lookup_autotuned("a2a_fused:T64:E2:D8") is None
+    finally:
+        pm.reset_autotuned()

@@ -1,0 +1,149 @@
+"""Compiles for a described TPU v5e, with no chip attached: the Pallas
+kernels at real widths must lower to Mosaic (``tpu_custom_call`` in the
+HLO), and the llama3.2-3b serving steps at published widths must fit one
+chip's 16 GiB.  Nothing runs, so these say nothing about results or time.
+
+The topology is described inside a module fixture, never at import: only
+one process may hold the TPU library, and the test workers all import this
+file.
+"""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh, SingleDeviceSharding
+
+HBM_BYTES = 16 * 2 ** 30
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    try:
+        desc = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:   # noqa: BLE001 - any failure means "cannot"
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a compile for a described chip is written to the persistent cache but
+    # cannot be read back without the chip: keep the cache out of it
+    before = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", before)
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def chip_plan(topo):
+    from repro.core.plan import ShardingPlan
+    devs = np.array(topo.devices[:1]).reshape(1, 1)
+    return ShardingPlan(mesh=Mesh(devs, axis_names=("data", "model")))
+
+
+def _sds(shape, dtype, sharding):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _a2a(lg, x):
+    from repro.kernels.a2a_fused import a2a_fused
+
+    def expert(j):
+        return lambda y: y * (j + 2.0) - j
+    return a2a_fused(lg, x, [expert(j) for j in range(8)], 4096,
+                     interpret=False)
+
+
+def _router(lg):
+    from repro.kernels.router_topk import router_topk
+    return router_topk(lg, 2, 1280, block_t=256, interpret=False)
+
+
+def _ssd(q, k, v, la):
+    from repro.kernels.ssd_scan import ssd_scan
+    return ssd_scan(q, k, v, la, chunk=128, interpret=False)
+
+
+def _flash(q, k, v):
+    from repro.kernels.flash_attention import flash_attention
+    return flash_attention(q, k, v, interpret=False)
+
+
+# (kernel, argument shapes and dtypes): a2a at the stream graph's widths,
+# the router at a Mixtral-like 8 experts top-2, the scan at Mamba2-like
+# head widths, attention at llama3.2-3b's 24/8 heads of 128
+KERNELS = {
+    "a2a_fused": (_a2a, [((4096, 8), jnp.float32),
+                         ((4096, 1024), jnp.float32)]),
+    "router_topk": (_router, [((4096, 8), jnp.float32)]),
+    "ssd_scan": (_ssd, [((2, 8, 2048, 128), jnp.bfloat16),
+                        ((2, 8, 2048, 128), jnp.bfloat16),
+                        ((2, 8, 2048, 64), jnp.bfloat16),
+                        ((2, 8, 2048), jnp.float32)]),
+    "flash_attention": (_flash, [((1, 24, 2048, 128), jnp.bfloat16),
+                                 ((1, 8, 2048, 128), jnp.bfloat16),
+                                 ((1, 8, 2048, 128), jnp.bfloat16)]),
+}
+
+
+@pytest.mark.kernels
+@pytest.mark.parametrize("name", sorted(KERNELS))
+def test_kernel_compiles_to_mosaic_for_v5e(name, one_chip):
+    fn, shapes = KERNELS[name]
+    args = [_sds(s, d, one_chip) for s, d in shapes]
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def _fits(compiled):
+    m = compiled.memory_analysis()
+    total = (m.argument_size_in_bytes + m.output_size_in_bytes
+             - m.alias_size_in_bytes + m.temp_size_in_bytes)
+    assert total < HBM_BYTES, (total, m)
+    return m
+
+
+@pytest.fixture(scope="module")
+def llama(chip_plan):
+    from repro.configs import get
+    from repro.models.lm import LM
+    from repro.models.params import shape_structs
+    cfg = get("llama3.2-3b")
+    return cfg, shape_structs(LM(cfg).param_defs(), chip_plan)
+
+
+def test_llama_prefill_fits_one_v5e(llama, chip_plan, one_chip):
+    from repro.runtime.steps import make_prefill_step
+    cfg, params = llama
+    step = jax.jit(make_prefill_step(cfg, chip_plan, 1024))
+    compiled = step.lower(
+        params, {"tokens": _sds((1, 128), jnp.int32, one_chip)}).compile()
+    m = _fits(compiled)
+    assert m.argument_size_in_bytes > 7 * 10 ** 9      # all 3.6B bf16 params
+
+
+def test_llama_decode_fits_one_v5e_with_cache_donated(llama, chip_plan,
+                                                      one_chip):
+    from repro.configs import cache_specs
+    from repro.runtime.steps import make_decode_step
+    cfg, params = llama
+    B, cache_len = 8, 1024
+    step = jax.jit(make_decode_step(cfg, chip_plan, cache_len),
+                   donate_argnums=1)
+    caches = cache_specs(cfg, B, cache_len, chip_plan)
+    compiled = step.lower(params, caches, {
+        "token": _sds((B, 1), jnp.int32, one_chip),
+        "pos": _sds((B,), jnp.int32, one_chip)}).compile()
+    m = _fits(compiled)
+    cache_bytes = sum(c.size * c.dtype.itemsize
+                      for c in jax.tree.leaves(caches))
+    assert m.alias_size_in_bytes >= cache_bytes   # updated in place
