@@ -72,6 +72,7 @@ from typing import Any, Deque, Dict, Iterator, List, Optional, Union
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from ..core.compiler import CompileConfig
 from ..core.graph import Deliver, StageHandle, pipeline
@@ -188,6 +189,20 @@ class _SLOState:
         self.ext_level = 0      # pushed down by the Supervisor, if attached
 
 
+# Spans on the profiler's clock, one per layer boundary, each on the thread
+# that does the work and around host work only; they record only while a
+# profiler session is on (``jax.profiler.start_trace``/``start_server``).
+# They never nest: a trace reader credits a device-idle gap to the host
+# event that overlaps it most, and a parent span would hide its children.
+# Spans of one request share ``req``; spans of one tick share ``step``.
+SPAN_PREFILL = "serve.prefill"  # prompt upload + prefill dispatch: req, len,
+#                                 queued_us (pick-up - submit)
+SPAN_INSERT = "serve.insert"    # slot insert + first token: req, slot,
+#                                 ready_us (insert - prefill done)
+SPAN_DECODE = "serve.decode"    # decode dispatch + copy start: step, batch
+SPAN_SYNC = "serve.sync"        # wait for the step's copy-out: step
+SPAN_COLLECT = "serve.collect"  # slot loop, exits, deliveries: step, finished
+
 _TICK = _Sentinel("TICK")     # the circulating batch step
 _DRAIN = _Sentinel("DRAIN")   # FF_EOS translated so admission can drain first
 _END = _Sentinel("END")       # client-side end-of-results marker
@@ -202,6 +217,7 @@ class _Ready:
     cache1: Any = None          # B=1 KV cache pytree
     prompt_len: int = 0
     error: Optional[BaseException] = None
+    done_t: float = 0.0         # when the prefill finished on the device
 
 
 class _BatchState:
@@ -217,8 +233,8 @@ class _BatchState:
         self.active_mask = np.zeros((B,), bool)
         self.last_toks: Optional[np.ndarray] = None
         self.last_conf: Optional[np.ndarray] = None
-        # the in-flight decode step: (next_tokens, confidence) device arrays
-        # dispatched by DecodeNode but not yet copied out — CollectNode
+        # the in-flight decode step: (step, next_tokens, confidence), the
+        # arrays dispatched by DecodeNode but not yet copied out — CollectNode
         # resolves them at the top of its turn, so the d2h copy (and the
         # compute remainder behind it) overlaps the hop between the nodes
         # and the next tick's slot-refill dispatch never waits on a host
@@ -257,11 +273,18 @@ class PrefillNode(FFNode):
             req = self._jobs.get()
             if req is EOS:
                 return
+            queued_us = int((time.perf_counter() - req.submit_t) * 1e6)
             try:
-                prompt = jnp.asarray(req.prompt, jnp.int32)[None, :]
-                tok, cache1 = self._prefill(self._params, prompt)
+                # the wait for the device stays outside the span, so that it
+                # hides no gap of the decode thread
+                with TraceAnnotation(SPAN_PREFILL, req=req.id,
+                                     len=len(req.prompt),
+                                     queued_us=queued_us):
+                    prompt = jnp.asarray(req.prompt, jnp.int32)[None, :]
+                    tok, cache1 = self._prefill(self._params, prompt)
                 tok.block_until_ready()
-                out = _Ready(req, tok, cache1, int(prompt.shape[1]))
+                out = _Ready(req, tok, cache1, int(prompt.shape[1]),
+                             done_t=time.perf_counter())
                 with self._stats_lock:
                     self.prefills += 1
             except Exception as e:  # noqa: BLE001 - re-raised by the handle
@@ -376,10 +399,14 @@ class CacheManager(FFNode):
                 continue
             slot = self.free.pop()
             self.active[slot] = req
-            st.caches, st.cur_tok, st.pos = self._insert(
-                st.caches, r.cache1, st.cur_tok, st.pos, jnp.asarray(slot),
-                r.tok, jnp.asarray(r.prompt_len, jnp.int32))
-            req.tokens.append(int(r.tok[0, 0]))
+            ready_us = int((time.perf_counter() - r.done_t) * 1e6)
+            with TraceAnnotation(SPAN_INSERT, req=req.id, slot=slot,
+                                 ready_us=ready_us):
+                st.caches, st.cur_tok, st.pos = self._insert(
+                    st.caches, r.cache1, st.cur_tok, st.pos,
+                    jnp.asarray(slot), r.tok,
+                    jnp.asarray(r.prompt_len, jnp.int32))
+                req.tokens.append(int(r.tok[0, 0]))
             st.active_mask[slot] = True
             self.inserts += 1
             self.acct.bump("admitted")
@@ -445,25 +472,28 @@ class DecodeNode(FFNode):
         if item is not _TICK:
             return item                   # pass-through (Deliver, drain...)
         st = self.state
-        nt, conf, st.caches = self._decode(
-            self.params, st.caches, {"token": st.cur_tok, "pos": st.pos})
-        st.cur_tok = nt
-        st.pos = st.pos + jnp.asarray(st.active_mask, jnp.int32)
-        self.steps += 1
-        # the overlapped boundary, serving edition: do NOT sync here — start
-        # the device->host copies and hand the unfinalized arrays down the
-        # loop.  CollectNode resolves them, so the copy-out (and compute
-        # remainder) rides under the decode->collect hop, and the next
-        # tick's CacheManager refill dispatches behind the in-flight step
-        # without a host sync in between
-        for leaf in (nt, conf):
-            copy = getattr(leaf, "copy_to_host_async", None)
-            if copy is not None:
-                try:
-                    copy()
-                except Exception:   # noqa: BLE001 - optional fast path
-                    pass
-        st.pending = (nt, conf)
+        step = self.steps
+        with TraceAnnotation(SPAN_DECODE, step=step,
+                             batch=int(st.active_mask.sum())):
+            nt, conf, st.caches = self._decode(
+                self.params, st.caches, {"token": st.cur_tok, "pos": st.pos})
+            st.cur_tok = nt
+            st.pos = st.pos + jnp.asarray(st.active_mask, jnp.int32)
+            self.steps += 1
+            # the overlapped boundary, serving edition: do NOT sync here —
+            # start the device->host copies and hand the unfinalized arrays
+            # down the loop.  CollectNode resolves them, so the copy-out (and
+            # compute remainder) rides under the decode->collect hop, and the
+            # next tick's CacheManager refill dispatches behind the in-flight
+            # step without a host sync in between
+            for leaf in (nt, conf):
+                copy = getattr(leaf, "copy_to_host_async", None)
+                if copy is not None:
+                    try:
+                        copy()
+                    except Exception:   # noqa: BLE001 - optional fast path
+                        pass
+        st.pending = (step, nt, conf)
         return _TICK
 
 
@@ -498,16 +528,11 @@ class CollectNode(FFNode):
             thr = thr * self.slo.policy.exit_margin
         return thr
 
-    def svc(self, item):
-        if item is not _TICK:
-            return item                   # pass-through
-        st = self.state
-        if st.pending is not None:        # resolve the in-flight decode step
-            nt, conf = st.pending
-            st.pending = None
-            st.last_toks = np.asarray(nt[:, 0])
-            st.last_conf = np.asarray(conf)
+    def _collect(self, st: _BatchState) -> int:
+        """Append each active slot's token and finish the requests that
+        exit; returns how many finished."""
         now = time.perf_counter()
+        finished = 0
         for slot in list(self.cm.active):
             req = self.cm.active[slot]
             if not st.active_mask[slot]:
@@ -536,6 +561,22 @@ class CollectNode(FFNode):
                 self.cm.release(slot)
                 self.acct.bump("finished")
                 self.ff_send_out(Deliver(req))
+                finished += 1
+        return finished
+
+    def svc(self, item):
+        if item is not _TICK:
+            return item                   # pass-through
+        st = self.state
+        step = -1
+        if st.pending is not None:        # resolve the in-flight decode step
+            step, nt, conf = st.pending
+            st.pending = None
+            with TraceAnnotation(SPAN_SYNC, step=step):
+                st.last_toks = np.asarray(nt[:, 0])
+                st.last_conf = np.asarray(conf)
+        with TraceAnnotation(SPAN_COLLECT, step=step) as span:
+            span.set_metadata(finished=self._collect(st))
         return _TICK                      # wrap_around -> loop head
 
 
