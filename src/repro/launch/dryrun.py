@@ -104,10 +104,11 @@ def _probe(cfg, plan, kind, mode, B, S):
 
     cache_arg = None
     if mode == "decode":
+        # a one-layer stack: decode blocks write into a stack at ``layer``
         cs = _cache_struct(kind, cfg, B, cfg.cache_len or S, 1)
         def leaf(t):
             shape, dtype, axes = t
-            return _sds(tuple(shape[1:]), dtype, plan, tuple(axes[1:]))
+            return _sds(tuple(shape), dtype, plan, tuple(axes))
         cache_arg = jax.tree.map(
             leaf, cs, is_leaf=lambda t: isinstance(t, tuple) and len(t) == 3
             and isinstance(t[0], tuple))
@@ -159,7 +160,7 @@ def _probe(cfg, plan, kind, mode, B, S):
             mrope = rest[0] if cfg.mrope else None
             return apply_block(kind, x, pl, cfg, plan, mode="decode",
                                cache=cache, positions=pos_, pos_offset=0,
-                               mrope_positions=mrope)[:2]
+                               mrope_positions=mrope, layer=0)[:2]
         args = [x, pl_structs, cache_arg, pos]
         if cfg.mrope:
             args.append(mp)

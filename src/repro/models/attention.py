@@ -119,23 +119,47 @@ def sdpa_streaming(q, k, v, *, causal: bool, window: int = 0,
 
 
 def _cache_axes(cfg):
-    """Logical axes of the KV cache (B, S_max, n_kv, hd): shard kv heads
-    when they divide the TP degree, else shard head_dim."""
+    """Logical axes of the KV cache (B, n_kv, S_max, hd): shard kv heads
+    when they divide the TP degree, else shard head_dim.
+
+    Heads come before positions so that each (slot, head) is one
+    contiguous (S_max, hd) matrix: the decode step's score and value
+    products then read a layer straight from the stacked cache, where a
+    positions-major cache costs a relayout copy of the layer every step."""
     if cfg.attn_parallel == "heads" and cfg.n_kv_heads % 16 == 0:
-        return ("batch", None, "tp", None)
+        return ("batch", "tp", None, None)
     return ("batch", None, None, "tp")
 
 
+def _write_rows(stack, new, layer, cache_pos):
+    """Store the step's rows ``new`` (B, 1, kv, hd) at ``(layer, b, :,
+    cache_pos[b])`` of the stacked cache (L, B, kv, S, hd): one write of
+    B x kv rows, in place when the stack is a donated buffer or a loop
+    carry.  Positions past the end clamp to the last row, as a dynamic
+    update slice does."""
+    new = new.astype(stack.dtype)
+    B, _, n_kv, _ = new.shape
+    if getattr(cache_pos, "ndim", 0) == 1:
+        b = jnp.arange(B)[:, None]
+        h = jnp.arange(n_kv)[None, :]
+        return stack.at[layer, b, h, cache_pos[:, None]].set(new[:, 0],
+                                                              mode="clip")
+    return jax.lax.dynamic_update_slice(
+        stack, new.transpose(0, 2, 1, 3)[None], (layer, 0, 0, cache_pos, 0))
+
+
 def attention(x, p, cfg, plan, *, positions, causal=True, window=0,
-              cache=None, cache_pos=None, mrope_positions=None,
+              cache=None, cache_pos=None, mrope_positions=None, layer=None,
               q_block: int = 2048, kv_block: int = 2048):
     """Attention block: projections + blocked grouped SDPA + output proj.
 
     train:    cache=None          -> (out, None)
     prefill:  cache='init'        -> (out, {k, v} padded to cfg.cache_len)
     decode:   cache={k, v} dict   -> (out, updated cache); x is (B, 1, d),
-              ``cache_pos`` the write slot (scalar or (B,)), ``positions``
-              (B, 1) global positions.
+              the cache is stacked (L, B, kv, S_max, hd) and ``layer`` this
+              block's index in it, ``cache_pos`` the write slot (scalar or
+              (B,)), ``positions`` (B, 1) global positions.  Only the B new
+              rows are written; attention reads layer ``layer`` in place.
     """
     B, S, _ = x.shape
     n_kv = cfg.n_kv_heads
@@ -170,27 +194,17 @@ def attention(x, p, cfg, plan, *, positions, causal=True, window=0,
         q = plan.constrain(q, "batch", "cp", None, None)
 
     if decode:
-        ca = _cache_axes(cfg)
-        if hasattr(cache_pos, "ndim") and getattr(cache_pos, "ndim", 0) == 1:
-            def upd(c, u, pp):
-                return jax.lax.dynamic_update_slice(c, u, (pp, 0, 0))
-            ck = jax.vmap(upd)(cache["k"], k.astype(cache["k"].dtype),
-                               cache_pos)
-            cv = jax.vmap(upd)(cache["v"], v.astype(cache["v"].dtype),
-                               cache_pos)
-        else:
-            ck = jax.lax.dynamic_update_slice(
-                cache["k"], k.astype(cache["k"].dtype), (0, cache_pos, 0, 0))
-            cv = jax.lax.dynamic_update_slice(
-                cache["v"], v.astype(cache["v"].dtype), (0, cache_pos, 0, 0))
-        ck = plan.constrain(ck, *ca)
-        cv = plan.constrain(cv, *ca)
-        new_cache = {"k": ck, "v": cv}
-        Sk = ck.shape[1]
+        ca = ("layers",) + _cache_axes(cfg)
+        sk = plan.constrain(_write_rows(cache["k"], k, layer, cache_pos), *ca)
+        sv = plan.constrain(_write_rows(cache["v"], v, layer, cache_pos), *ca)
+        new_cache = {"k": sk, "v": sv}
+        ck = jax.lax.dynamic_index_in_dim(sk, layer, 0, keepdims=False)
+        cv = jax.lax.dynamic_index_in_dim(sv, layer, 0, keepdims=False)
+        Sk = ck.shape[2]
         k_pos = jnp.arange(Sk)
         scale = 1.0 / math.sqrt(cfg.head_dim)
         qg = _group(q, n_kv).astype(jnp.float32) * scale   # (B,1,kv,g,D)
-        s = jnp.einsum("bqhgd,bkhd->bqhgk", qg, ck.astype(jnp.float32))
+        s = jnp.einsum("bqhgd,bhkd->bqhgk", qg, ck.astype(jnp.float32))
         ring = window > 0 and Sk == window
         valid = k_pos[None, None, :] <= positions[:, :, None]
         if window and window > 0 and not ring:
@@ -201,7 +215,7 @@ def attention(x, p, cfg, plan, *, positions, causal=True, window=0,
             valid = valid | (positions[:, :, None] >= window)
         s = jnp.where(valid[:, :, None, None, :], s, NEG_INF)
         w = jax.nn.softmax(s.astype(jnp.float32), axis=-1)
-        out = jnp.einsum("bqhgk,bkhd->bqhgd", w, cv.astype(jnp.float32))
+        out = jnp.einsum("bqhgk,bhkd->bqhgd", w, cv.astype(jnp.float32))
         out = out.reshape(B, S, q.shape[2], cfg.head_dim).astype(x.dtype)
     else:
         if cfg.attn_parallel == "cp":
@@ -225,8 +239,8 @@ def attention(x, p, cfg, plan, *, positions, causal=True, window=0,
                 pad = [(0, 0), (0, tgt - S), (0, 0), (0, 0)]
                 ck = jnp.pad(ck, pad)
                 cv = jnp.pad(cv, pad)
-            new_cache = {"k": plan.constrain(ck, *ca),
-                         "v": plan.constrain(cv, *ca)}
+            new_cache = {"k": plan.constrain(ck.transpose(0, 2, 1, 3), *ca),
+                         "v": plan.constrain(cv.transpose(0, 2, 1, 3), *ca)}
 
     head_ax2 = "tp" if cfg.attn_parallel == "heads" else None
     wo = plan.gather_fsdp(p["wo"], (head_ax2, None, "fsdp"))

@@ -17,7 +17,10 @@ Sharding: only logical axes (core/plan.py).  Embedding and cross-entropy are
 vocab-parallel (Megatron-style shard_map) so full logits are never
 materialized.  The layer scans are the *only* ``while`` loops in any step
 function — launch/dryrun.py relies on this for exact loop-corrected cost
-accounting (see DESIGN.md §7).
+accounting.  Decode keeps it so: each layer stores its new K/V rows with
+one batched scatter into the carried cache, which the TPU compiler emits
+as an in-place fusion, not a loop (tests/test_tpu_compile.py counts the
+loops of a compiled decode step).
 """
 
 from __future__ import annotations
@@ -137,12 +140,12 @@ def _residual(x, delta, plan):
 
 def dense_block(x, p, cfg, plan, *, mode, cache=None, positions=None,
                 pos_offset=0, mrope_positions=None, causal=True,
-                window=0, moe=False):
+                window=0, moe=False, layer=None):
     aux = {}
     xn = apply_norm(x, p["ln1"], cfg.norm)
     a, new_cache = attention(
         xn, p["attn"], cfg, plan, positions=positions, causal=causal,
-        window=window, cache=cache, cache_pos=pos_offset,
+        window=window, cache=cache, cache_pos=pos_offset, layer=layer,
         mrope_positions=mrope_positions,
         q_block=cfg.q_block, kv_block=cfg.kv_block)
     x = _residual(x, a, plan)
@@ -168,35 +171,48 @@ def dense_defs(cfg, layers, moe=False, kind_cfg=None):
     return d
 
 
+def _layer_of(stack, layer):
+    """One layer of a stacked cache tree, read in place."""
+    return jax.tree.map(
+        lambda t: lax.dynamic_index_in_dim(t, layer, 0, keepdims=False), stack)
+
+
 def apply_block(kind, x, p, cfg, plan, *, mode, cache=None, positions=None,
-                pos_offset=0, mrope_positions=None, enc_out=None):
-    """Uniform block dispatch. Returns (x, new_cache, aux)."""
+                pos_offset=0, mrope_positions=None, enc_out=None, layer=None):
+    """Uniform block dispatch. Returns (x, new_cache, aux).
+
+    In decode ``cache`` is the kind's whole stacked cache and ``layer`` the
+    block's index in it; the stack comes back with only this layer's new
+    entries written: the new K/V rows of an append-only cache, the whole
+    (small) state of a recurrent block, nothing of a read-only cross cache.
+    """
     window = cfg.window if cfg.attn_kind == "swa" else 0
     if kind in ("dense", "moe"):
         return dense_block(x, p, cfg, plan, mode=mode, cache=cache,
                            positions=positions, pos_offset=pos_offset,
-                           mrope_positions=mrope_positions,
+                           mrope_positions=mrope_positions, layer=layer,
                            causal=True, window=window, moe=(kind == "moe"))
     if kind == "shared_attn":
         return dense_block(x, p, cfg, plan, mode=mode, cache=cache,
                            positions=positions, pos_offset=pos_offset,
-                           causal=True, window=cfg.shared_attn_window)
+                           layer=layer, causal=True,
+                           window=cfg.shared_attn_window)
     if kind == "enc":
         return dense_block(x, p, cfg, plan, mode=mode, cache=None,
                            positions=positions, causal=False, window=0)
     if kind == "dec":
         aux = {}
+        decode = isinstance(cache, dict)
         xn = apply_norm(x, p["ln1"], cfg.norm)
         a, new_self = attention(xn, p["attn"], cfg, plan, positions=positions,
                                 causal=True, window=0, cache=(
-                                    cache["self"] if isinstance(cache, dict)
-                                    else cache),
-                                cache_pos=pos_offset,
+                                    cache["self"] if decode else cache),
+                                cache_pos=pos_offset, layer=layer,
                                 q_block=cfg.q_block, kv_block=cfg.kv_block)
         x = _residual(x, a, plan)
         xn = apply_norm(x, p["ln_x"], cfg.norm)
-        if isinstance(cache, dict):         # decode: cached cross-kv
-            ckv = cache["cross"]
+        if decode:                          # cached cross-kv, read only
+            ckv = _layer_of(cache["cross"], layer)
         else:
             ckv = cross_kv(enc_out, p["xattn"], cfg, plan)
         ca = cross_attention(xn, p["xattn"], ckv, cfg, plan)
@@ -204,17 +220,26 @@ def apply_block(kind, x, p, cfg, plan, *, mode, cache=None, positions=None,
         xn = apply_norm(x, p["ln2"], cfg.norm)
         x = _residual(x, mlp(xn, p["mlp"], cfg.act, plan), plan)
         new_cache = None
-        if cache is not None:
+        if decode:
+            new_cache = {"self": new_self, "cross": cache["cross"]}
+        elif cache is not None:
             new_cache = {"self": new_self, "cross": ckv}
         return x, new_cache, aux
-    if kind == "mamba2":
-        x, st = mamba2_block(x, p, cfg, plan, state=cache, chunk=cfg.gla_chunk)
-        return x, st, {}
-    if kind == "mlstm":
-        x, st = mlstm_block(x, p, cfg, plan, state=cache, chunk=cfg.gla_chunk)
-        return x, st, {}
-    if kind == "slstm":
-        x, st = slstm_block(x, p, cfg, plan, state=cache)
+    if kind in ("mamba2", "mlstm", "slstm"):
+        decode = isinstance(cache, dict)
+        state = _layer_of(cache, layer) if decode else cache
+        if kind == "mamba2":
+            x, st = mamba2_block(x, p, cfg, plan, state=state,
+                                 chunk=cfg.gla_chunk)
+        elif kind == "mlstm":
+            x, st = mlstm_block(x, p, cfg, plan, state=state,
+                                chunk=cfg.gla_chunk)
+        else:
+            x, st = slstm_block(x, p, cfg, plan, state=state)
+        if decode:                          # the new state replaces the old
+            st = jax.tree.map(
+                lambda t, s: lax.dynamic_update_index_in_dim(t, s, layer, 0),
+                cache, st)
         return x, st, {}
     raise ValueError(kind)
 
@@ -249,14 +274,16 @@ def _cache_struct(kind, cfg, B, S_max, layers):
     if kind in ("dense", "moe", "shared_attn", "enc", "dec"):
         from .attention import _cache_axes
         ca = ("layers",) + _cache_axes(cfg)
-        def kvd(S):
-            return {"k": ((layers, B, S, cfg.n_kv_heads, cfg.head_dim),
-                          jnp.bfloat16, ca),
-                    "v": ((layers, B, S, cfg.n_kv_heads, cfg.head_dim),
-                          jnp.bfloat16, ca)}
+        def kvd(shape, axes):
+            return {"k": (shape, jnp.bfloat16, axes),
+                    "v": (shape, jnp.bfloat16, axes)}
+        kv = kvd((layers, B, cfg.n_kv_heads, S_max, cfg.head_dim), ca)
         if kind == "dec":
-            return {"self": kvd(S_max), "cross": kvd(cfg.enc_len)}
-        return kvd(S_max)
+            # the cross cache keeps cross_kv's positions-major layout
+            cross = kvd((layers, B, cfg.enc_len, cfg.n_kv_heads,
+                         cfg.head_dim), ca[:2] + (ca[3], ca[2], ca[4]))
+            return {"self": kv, "cross": cross}
+        return kv
     if kind == "mamba2":
         return mamba2_state_defs(cfg, B, layers)
     if kind == "mlstm":
@@ -303,13 +330,20 @@ class LM:
     def _run_segments(self, params, x, *, mode, caches=None, positions=None,
                       pos_offset=0, mrope_positions=None, enc_out=None,
                       segments=None):
-        """Run the segment list; returns (x, new_caches, aux_sum)."""
+        """Run the segment list; returns (x, new_caches, aux_sum).
+
+        Decode carries each kind's whole stacked cache through its layer
+        scan (and from one segment of the kind to the next), with the
+        layer's index in the stack as the scanned input, so every block
+        writes its new entries into the donated buffer in place."""
         cfg = self.cfg
         segments = segments if segments is not None else cfg.segments
         offsets = {k: 0 for k, _ in segments}
         shared_i = 0
         aux_tot = {}
         new_caches: Dict[str, Any] = {}
+        if mode == "decode":
+            caches = dict(caches)
 
         def add_aux(a):
             for k, v in a.items():
@@ -319,27 +353,23 @@ class LM:
             if kind == "shared_attn":
                 p = params["shared"]
                 if mode == "train":
-                    cache_l = None
-                elif mode == "prefill":
-                    cache_l = "init"
-                else:
-                    cache_l = jax.tree.map(lambda t: t[shared_i],
-                                           caches["shared_attn"])
-                if mode == "train":
                     blk = jax.checkpoint(
                         lambda xx, pp: apply_block(
                             "shared_attn", xx, pp, cfg, self._plan, mode=mode,
                             cache=None, positions=positions,
                             pos_offset=pos_offset)[0])
                     x = blk(x, p)
-                    nc = None
-                else:
-                    x, nc, aux = apply_block(
+                elif mode == "prefill":
+                    x, nc, _ = apply_block(
                         "shared_attn", x, p, cfg, self._plan, mode=mode,
-                        cache=cache_l, positions=positions,
+                        cache="init", positions=positions,
                         pos_offset=pos_offset)
-                if nc is not None:
                     new_caches.setdefault("shared_attn", []).append(nc)
+                else:
+                    x, caches[kind], _ = apply_block(
+                        "shared_attn", x, p, cfg, self._plan, mode=mode,
+                        cache=caches[kind], positions=positions,
+                        pos_offset=pos_offset, layer=shared_i)
                 shared_i += 1
                 continue
 
@@ -377,19 +407,22 @@ class LM:
                 x, ncs = lax.scan(body, x, stack)
                 new_caches.setdefault(kind, []).append(ncs)
             else:  # decode
-                cache_stack = jax.tree.map(
-                    lambda t: t[start:start + count], caches[kind])
-                def body(xx, pc, _kind=kind):
-                    pl, cl = pc
-                    y, nc, _ = apply_block(
+                def body(carry, pi, _kind=kind):
+                    xx, cs = carry
+                    pl, li = pi
+                    y, cs, _ = apply_block(
                         _kind, xx, pl, cfg, plan, mode="decode",
-                        cache=cl, positions=positions, pos_offset=pos_offset,
-                        mrope_positions=mrope_positions, enc_out=enc_out)
-                    return y, nc
-                x, ncs = lax.scan(body, x, (stack, cache_stack))
-                new_caches.setdefault(kind, []).append(ncs)
+                        cache=cs, positions=positions, pos_offset=pos_offset,
+                        mrope_positions=mrope_positions, enc_out=enc_out,
+                        layer=li)
+                    return (y, cs), None
+                layer_ids = start + jnp.arange(count, dtype=jnp.int32)
+                (x, caches[kind]), _ = lax.scan(body, (x, caches[kind]),
+                                                (stack, layer_ids))
 
-        # concatenate per-kind cache pieces back into full stacks
+        if mode == "decode":
+            return x, caches, aux_tot
+        # prefill: concatenate per-kind cache pieces into full stacks
         out_caches = {}
         for kind, pieces in new_caches.items():
             if kind == "shared_attn":

@@ -115,6 +115,100 @@ def test_swa_ring_cache_matches_full_window(plan, rng):
     np.testing.assert_allclose(a, b, rtol=0.2, atol=0.2)
 
 
+# case -> (arch, overrides): every form of decode cache the tiny archs have
+WRITE_CASES = {
+    "dense": ("ff-tiny", {}),
+    "moe": ("mixtral-8x7b", {"capacity_factor": 8.0}),
+    "swa_ring": ("mixtral-8x7b", {"capacity_factor": 8.0, "window": 8}),
+    "shared_attn": ("zamba2-1.2b", {}),
+    "dec_cross": ("whisper-medium", {}),
+    "xlstm_state": ("xlstm-125m", {}),
+}
+RECURRENT = ("mamba2", "mlstm", "slstm")
+
+
+def _bits(x):
+    a = np.asarray(x)
+    return a.view(np.dtype(f"u{a.itemsize}"))
+
+
+@pytest.mark.parametrize("case", sorted(WRITE_CASES))
+def test_decode_writes_only_its_rows(case, plan, rng):
+    """One decode step over slots at different positions leaves every
+    cache entry outside the written ``(layer, b, :, pos[b])`` K/V rows bit
+    for bit as it was, and writes the rows a prefill of the one-longer
+    prompt holds there; a cross cache is not touched, a recurrent state is
+    replaced whole in its own shape."""
+    import dataclasses
+    arch, over = WRITE_CASES[case]
+    cfg = dataclasses.replace(get(arch).reduced(), **over)
+    params = init_state(cfg, plan, rng)["params"]
+    CL = 16
+    ring = cfg.attn_kind == "swa" and cfg.window < CL
+    lens = [10, 11] if ring else [5, 6]       # slot b's prompt length
+    toks = jax.random.randint(rng, (2, max(lens) + 1), 0, cfg.vocab)
+    frames = 0.1 * jax.random.normal(rng, (1, 8, cfg.d_model), jnp.bfloat16)
+    prefill = jax.jit(make_prefill_step(cfg, plan, CL))
+
+    def slot_cache(b, n):
+        batch = {"tokens": toks[b:b + 1, :n]}
+        if cfg.family == "encdec":
+            batch["frames"] = frames
+        return prefill(params, batch)[1]
+
+    caches = jax.tree.map(lambda *cs: jnp.concatenate(cs, axis=1),
+                          *[slot_cache(b, n) for b, n in enumerate(lens)])
+    refs = [slot_cache(b, n + 1) for b, n in enumerate(lens)]
+    wpos = [n % cfg.window if ring else n for n in lens]
+    noise = np.random.default_rng(0)
+
+    def kv_of(c, kind):
+        return c["self"] if kind == "dec" else c
+
+    if not ring:      # unused rows (masked at decode) hold noise, not zeros
+        for kind, c in caches.items():
+            if kind in RECURRENT:
+                continue
+            kv = dict(kv_of(c, kind))
+            for name, t in kv.items():
+                a = np.array(t, np.float32)
+                for b, n in enumerate(lens):
+                    a[:, b, :, n:] = noise.normal(size=a[:, b, :, n:].shape)
+                kv[name] = jnp.asarray(a, t.dtype)
+            caches[kind] = dict(c, self=kv) if kind == "dec" else kv
+
+    decode = jax.jit(make_decode_step(cfg, plan, CL))
+    _, _, new = decode(params, caches, {
+        "token": toks[jnp.arange(2), jnp.asarray(lens)][:, None],
+        "pos": jnp.asarray(lens, jnp.int32)})
+
+    assert jax.tree.structure(new) == jax.tree.structure(caches)
+    for a, b in zip(jax.tree.leaves(caches), jax.tree.leaves(new)):
+        assert a.shape == b.shape and a.dtype == b.dtype
+    for kind, c in caches.items():
+        if kind in RECURRENT:
+            for a, b in zip(jax.tree.leaves(c), jax.tree.leaves(new[kind])):
+                for layer in range(a.shape[0]):
+                    assert not np.array_equal(_bits(a[layer]),
+                                              _bits(b[layer]))
+            continue
+        if kind == "dec":
+            for a, b in zip(jax.tree.leaves(c["cross"]),
+                            jax.tree.leaves(new[kind]["cross"])):
+                assert np.array_equal(_bits(a), _bits(b))
+        for name, old in kv_of(c, kind).items():
+            got = kv_of(new[kind], kind)[name]
+            written = np.zeros(old.shape, bool)       # (L, B, kv, S, hd)
+            for b, p in enumerate(wpos):
+                written[:, b, :, p] = True
+            assert np.array_equal(_bits(old)[~written], _bits(got)[~written])
+            for b, p in enumerate(wpos):
+                want = kv_of(refs[b][kind], kind)[name][:, 0, :, p]
+                np.testing.assert_allclose(
+                    np.asarray(got[:, b, :, p], np.float32),
+                    np.asarray(want, np.float32), rtol=5e-2, atol=5e-2)
+
+
 def test_moe_block_matches_dense_mixture(plan, rng):
     """With ample capacity, the scatter/dispatch MoE == explicit per-token
     mixture of expert FFNs (the farm's collector is exact)."""

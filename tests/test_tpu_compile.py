@@ -1,14 +1,18 @@
 """Compiles for a described TPU v5e, with no chip attached: the Pallas
 kernels at real widths must lower to Mosaic (``tpu_custom_call`` in the
-HLO), and the llama3.2-3b serving steps at published widths must fit one
-chip's 16 GiB.  Nothing runs, so these say nothing about results or time.
+HLO), the llama3.2-3b serving steps at published widths must fit one
+chip's 16 GiB, and a decode step must update its donated KV cache in
+place, with no copy of the cache or of a layer of it.  Nothing runs, so
+these say nothing about results or time.
 
 The topology is described inside a module fixture, never at import: only
 one process may hold the TPU library, and the test workers all import this
 file.
 """
 
+import math
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -131,19 +135,87 @@ def test_llama_prefill_fits_one_v5e(llama, chip_plan, one_chip):
     assert m.argument_size_in_bytes > 7 * 10 ** 9      # all 3.6B bf16 params
 
 
-def test_llama_decode_fits_one_v5e_with_cache_donated(llama, chip_plan,
-                                                      one_chip):
+def _decode(cfg, params, chip_plan, one_chip, B, cache_len):
+    """The serving engine's decode step (per-slot positions, cache donated)
+    compiled for one chip; returns (compiled, cache structs)."""
     from repro.configs import cache_specs
     from repro.runtime.steps import make_decode_step
-    cfg, params = llama
-    B, cache_len = 8, 1024
     step = jax.jit(make_decode_step(cfg, chip_plan, cache_len),
                    donate_argnums=1)
     caches = cache_specs(cfg, B, cache_len, chip_plan)
     compiled = step.lower(params, caches, {
         "token": _sds((B, 1), jnp.int32, one_chip),
         "pos": _sds((B,), jnp.int32, one_chip)}).compile()
+    return compiled, caches
+
+
+_ARRAY = re.compile(r"^\s*(?:ROOT )?%(\S+) = \w+\[([\d,]*)\]\S* (\S+?)\(")
+
+
+def _cache_sized(hlo, caches, n_layers):
+    """Instructions that materialize the cache or one layer of it: any
+    ``copy`` with a stack's or a layer's element count, and any other
+    instruction outside a fusion with a layer's.  The in-place writes
+    (stack-sized results of fusions, scatters, the loop) are not listed."""
+    stacks = {math.prod(c.shape) for c in jax.tree.leaves(caches)}
+    layers = {n // n_layers for n in stacks}
+    fused = set(re.findall(r" fusion\(.*?calls=%([\w.\-]+)", hlo))
+    found, comp = [], None
+    for line in hlo.splitlines():
+        head = re.match(r"^(?:ENTRY )?%([\w.\-]+) ", line)
+        if head:
+            comp = head.group(1)
+            continue
+        m = _ARRAY.match(line)
+        if not m:
+            continue
+        name, dims, op = m.groups()
+        n = math.prod(int(d) for d in dims.split(",")) if dims else 1
+        if op == "copy" and n in stacks | layers:
+            found.append(name)
+        elif comp not in fused and n in layers and op != "parameter":
+            found.append(name)
+    return found
+
+
+def test_llama_decode_fits_one_v5e_with_cache_donated(llama, chip_plan,
+                                                      one_chip):
+    cfg, params = llama
+    compiled, caches = _decode(cfg, params, chip_plan, one_chip, 8, 1024)
     m = _fits(compiled)
     cache_bytes = sum(c.size * c.dtype.itemsize
                       for c in jax.tree.leaves(caches))
     assert m.alias_size_in_bytes >= cache_bytes   # updated in place
+    assert m.temp_size_in_bytes < cache_bytes / cfg.n_layers, m
+
+
+@pytest.fixture(scope="module")
+def yi_stage(chip_plan):
+    """Yi-34B at published widths, 8 of its 60 layers: the benchmark's
+    serving configuration."""
+    import dataclasses
+    from repro.configs import get
+    from repro.models.lm import LM
+    from repro.models.params import shape_structs
+    cfg = dataclasses.replace(get("yi-34b"), n_layers=8)
+    return cfg, shape_structs(LM(cfg).param_defs(), chip_plan)
+
+
+def test_yi_stage_decode_writes_cache_rows_in_place(yi_stage, chip_plan,
+                                                    one_chip):
+    """16 slots of 2,560: the step writes its new K/V rows into the donated
+    1.34 GB cache and reads each layer where it lies, so its scratch stays
+    below one layer's K+V, no copy of the cache or of a layer is made, and
+    the layer scan is the only loop."""
+    from repro.models.lm import LM
+    cfg, params = yi_stage
+    compiled, caches = _decode(cfg, params, chip_plan, one_chip, 16, 2560)
+    m = _fits(compiled)
+    cache_bytes = sum(c.size * c.dtype.itemsize
+                      for c in jax.tree.leaves(caches))
+    assert m.alias_size_in_bytes >= cache_bytes
+    assert m.temp_size_in_bytes < cache_bytes / cfg.n_layers, m
+    hlo = compiled.as_text()
+    assert _cache_sized(hlo, caches, cfg.n_layers) == []
+    scans = sum(n for _, _, n in LM(cfg).loop_specs("decode"))
+    assert len(re.findall(r" while\(", hlo)) == scans
