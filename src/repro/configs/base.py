@@ -39,9 +39,22 @@ class Config:
     source: str = ""                 # provenance note
 
     # attention
-    attn_kind: str = "full"          # full | swa
+    attn_kind: str = "full"          # full | swa | mla
     window: int = 0
     rope_theta: float = 1e4
+    # YaRN (DeepSeek-V3's rope scaling): factor 0 leaves rope unscaled
+    rope_factor: float = 0.0
+    rope_original_len: int = 4096
+    rope_beta_fast: float = 32.0
+    rope_beta_slow: float = 1.0
+    rope_mscale_all_dim: float = 1.0
+    # latent attention (attn_kind="mla"): low-rank q and kv, a decoupled
+    # rotary part shared by the heads; the cache holds the kv latent
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
     use_rope: bool = True
     mrope: bool = False
     attn_parallel: str = "heads"     # heads | cp
@@ -60,6 +73,15 @@ class Config:
     n_shared_experts: int = 0
     capacity_factor: float = 1.25
     moe_mode: str = "ep"             # ep | tp
+    first_k_dense: int = 0           # leading dense layers before the MoE
+    router_score: str = "softmax"    # softmax | sigmoid
+    router_bias: bool = False        # noaux_tc: a bias picks, never weighs
+    routed_scale: float = 1.0        # routed_scaling_factor
+    # this chip's share of the experts: it routes over all n_experts and
+    # computes experts [expert_offset, expert_offset + n_experts_held) for
+    # every token routed to them (0: the layer holds them all)
+    n_experts_held: int = 0
+    expert_offset: int = 0
 
     # ssm / linear recurrence
     ssm_state: int = 0
@@ -101,6 +123,9 @@ class Config:
         if self.family == "encdec":
             return [("enc", self.enc_layers), ("dec", self.dec_layers)]
         if self.family == "moe":
+            if self.first_k_dense:
+                return [("dense", self.first_k_dense),
+                        ("moe", self.n_layers - self.first_k_dense)]
             return [("moe", self.n_layers)]
         return [("dense", self.n_layers)]
 
@@ -179,6 +204,14 @@ class Config:
             n_experts=min(self.n_experts, 4) if self.n_experts else 0,
             top_k=min(self.top_k, 2) if self.top_k else 0,
             moe_d_ff=64 if self.moe_d_ff else 0,
+            n_experts_held=min(self.n_experts_held, 2)
+            if self.n_experts_held else 0,
+            expert_offset=0,
+            q_lora_rank=32 if self.q_lora_rank else 0,
+            kv_lora_rank=32 if self.kv_lora_rank else 0,
+            qk_nope_head_dim=16 if self.qk_nope_head_dim else 0,
+            qk_rope_head_dim=8 if self.qk_rope_head_dim else 0,
+            v_head_dim=16 if self.v_head_dim else 0,
             ssm_state=16 if self.ssm_state else 0,
             ssm_headdim=16 if self.ssm_state or self.family == "ssm" else 64,
             gla_chunk=16,

@@ -73,14 +73,17 @@ def _block_mask(qlo, qhi, klo, khi, causal, window):
 
 def sdpa_streaming(q, k, v, *, causal: bool, window: int = 0,
                    q_block: Optional[int] = 2048, kv_block: int = 2048,
-                   q_offset: int = 0):
+                   q_offset: int = 0, scale: Optional[float] = None):
     """Blocked streaming-softmax grouped attention.
 
-    q: (B, Sq, kv, g, D); k/v: (B, Sk, kv, D).
-    ``q_block=None`` disables query blocking (context-parallel mode)."""
+    q: (B, Sq, kv, g, D); k: (B, Sk, kv, D); v: (B, Sk, kv, Dv).
+    ``q_block=None`` disables query blocking (context-parallel mode);
+    ``scale`` defaults to 1/sqrt(D)."""
     B, Sq, KV, G, D = q.shape
+    Dv = v.shape[-1]
     Sk = k.shape[1]
-    scale = 1.0 / math.sqrt(D)
+    if scale is None:
+        scale = 1.0 / math.sqrt(D)
     qf = q.astype(jnp.float32) * scale
     qb = Sq if q_block is None else min(q_block, Sq)
     skip = q_block is not None
@@ -96,7 +99,7 @@ def sdpa_streaming(q, k, v, *, causal: bool, window: int = 0,
                 klo = max(0, gqlo - window + 1)
                 klo = (klo // kv_block) * kv_block
         qc = qf[:, qlo:qhi]
-        acc = jnp.zeros((B, qhi - qlo, KV, G, D), jnp.float32)
+        acc = jnp.zeros((B, qhi - qlo, KV, G, Dv), jnp.float32)
         m = jnp.full((B, qhi - qlo, KV, G), NEG_INF, jnp.float32)
         l = jnp.zeros((B, qhi - qlo, KV, G), jnp.float32)
         for blo in range(klo, khi, kv_block):
@@ -115,7 +118,7 @@ def sdpa_streaming(q, k, v, *, causal: bool, window: int = 0,
             m = m_new
         outs.append(acc / jnp.maximum(l, 1e-30)[..., None])
     out = outs[0] if len(outs) == 1 else jnp.concatenate(outs, axis=1)
-    return out.reshape(B, Sq, KV * G, D).astype(q.dtype)
+    return out.reshape(B, Sq, KV * G, Dv).astype(q.dtype)
 
 
 def _cache_axes(cfg):

@@ -105,10 +105,12 @@ def rope_freqs(head_dim: int, theta: float):
                             / head_dim))
 
 
-def apply_rope(x, positions, theta: float = 1e4):
-    """x: (B, S, H, D); positions: (B, S) int32."""
+def apply_rope(x, positions, theta: float = 1e4, freqs=None):
+    """x: (B, S, H, D); positions: (B, S) int32; ``freqs`` (D/2,) in place
+    of the plain ones of ``theta`` (a scaled rope)."""
     d = x.shape[-1]
-    freqs = rope_freqs(d, theta)                       # (d/2,)
+    if freqs is None:
+        freqs = rope_freqs(d, theta)                   # (d/2,)
     ang = positions[..., None].astype(jnp.float32) * freqs   # (B,S,d/2)
     cos, sin = jnp.cos(ang)[:, :, None, :], jnp.sin(ang)[:, :, None, :]
     x1, x2 = x[..., 0::2], x[..., 1::2]

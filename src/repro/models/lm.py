@@ -10,6 +10,10 @@ under ``lax.scan`` (stacked params, full remat).  Block kinds:
   shared_attn  zamba2's shared transformer block (same params every call —
                the broadcast/MISD farm: one task stream, one worker reused)
 
+With ``attn_kind="mla"`` dense and moe blocks take latent attention
+(models/mla.py) in place of GQA and cache one latent row per position
+(kimi-k2: a leading dense layer, then MoE layers).
+
 Families 'encdec' (whisper) and 'vlm' (qwen2-vl) reuse the same machinery
 with stub frontends (precomputed frame/patch embeddings per the assignment).
 
@@ -37,6 +41,8 @@ from jax.sharding import PartitionSpec as P
 
 from .attention import attention, attn_defs, cross_attention, cross_kv
 from .layers import apply_norm, mlp, mlp_defs, norm_defs
+from .mla import CACHE_AXES as MLA_CACHE_AXES
+from .mla import cache_width, mla_attention, mla_defs
 from .moe import moe_block, moe_defs
 from .params import ParamDef, init_params, shape_structs
 from .ssm import mamba2_block, mamba2_defs, mamba2_state_defs
@@ -143,11 +149,17 @@ def dense_block(x, p, cfg, plan, *, mode, cache=None, positions=None,
                 window=0, moe=False, layer=None):
     aux = {}
     xn = apply_norm(x, p["ln1"], cfg.norm)
-    a, new_cache = attention(
-        xn, p["attn"], cfg, plan, positions=positions, causal=causal,
-        window=window, cache=cache, cache_pos=pos_offset, layer=layer,
-        mrope_positions=mrope_positions,
-        q_block=cfg.q_block, kv_block=cfg.kv_block)
+    if cfg.attn_kind == "mla":
+        a, new_cache = mla_attention(
+            xn, p["attn"], cfg, plan, positions=positions, cache=cache,
+            cache_pos=pos_offset, layer=layer, q_block=cfg.q_block,
+            kv_block=cfg.kv_block)
+    else:
+        a, new_cache = attention(
+            xn, p["attn"], cfg, plan, positions=positions, causal=causal,
+            window=window, cache=cache, cache_pos=pos_offset, layer=layer,
+            mrope_positions=mrope_positions,
+            q_block=cfg.q_block, kv_block=cfg.kv_block)
     x = _residual(x, a, plan)
     xn = apply_norm(x, p["ln2"], cfg.norm)
     if moe:
@@ -162,7 +174,8 @@ def dense_defs(cfg, layers, moe=False, kind_cfg=None):
     d = {
         "ln1": norm_defs(cfg.d_model, cfg.norm, layers),
         "ln2": norm_defs(cfg.d_model, cfg.norm, layers),
-        "attn": attn_defs(cfg, layers),
+        "attn": (mla_defs if cfg.attn_kind == "mla" else attn_defs)(
+            cfg, layers),
     }
     if moe:
         d["moe"] = moe_defs(cfg, layers)
@@ -271,6 +284,9 @@ def block_defs(kind, cfg, layers):
 
 def _cache_struct(kind, cfg, B, S_max, layers):
     """(shape, dtype, axes) templates for one stack's decode cache."""
+    if kind in ("dense", "moe") and cfg.attn_kind == "mla":
+        return {"ckv": ((layers, B, S_max, cache_width(cfg)), jnp.bfloat16,
+                        MLA_CACHE_AXES)}
     if kind in ("dense", "moe", "shared_attn", "enc", "dec"):
         from .attention import _cache_axes
         ca = ("layers",) + _cache_axes(cfg)
@@ -291,6 +307,10 @@ def _cache_struct(kind, cfg, B, S_max, layers):
     if kind == "slstm":
         return slstm_state_defs(cfg, B, layers)
     raise ValueError(kind)
+
+
+def _step_stats(aux) -> Dict[str, Any]:
+    return {"moe_hits": aux["moe_hits"]} if "moe_hits" in aux else {}
 
 
 # ---------------------------------------------------------------------------
@@ -335,13 +355,18 @@ class LM:
         Decode carries each kind's whole stacked cache through its layer
         scan (and from one segment of the kind to the next), with the
         layer's index in the stack as the scanned input, so every block
-        writes its new entries into the donated buffer in place."""
+        writes its new entries into the donated buffer in place.
+
+        In prefill and decode, an expert layer that holds a share of the
+        experts reports which tokens each held expert took: ``aux_sum``
+        then holds ``moe_hits``, (MoE layers, tokens, held) int32."""
         cfg = self.cfg
         segments = segments if segments is not None else cfg.segments
         offsets = {k: 0 for k, _ in segments}
         shared_i = 0
         aux_tot = {}
         new_caches: Dict[str, Any] = {}
+        hits = []
         if mode == "decode":
             caches = dict(caches)
 
@@ -389,8 +414,7 @@ class LM:
                             mrope_positions=mrope_positions, enc_out=enc_out)
                         return y, aux
                     y, aux = jax.checkpoint(blk)(xx, pl)
-                    aux_c = {k: aux_c.get(k, 0.0) + v for k, v in aux.items()} \
-                        if aux else aux_c
+                    aux_c = {k: v + aux[k] for k, v in aux_c.items()}
                     return (y, aux_c), None
                 aux0 = {"moe_lb": jnp.zeros((), jnp.float32),
                         "moe_z": jnp.zeros((), jnp.float32)} \
@@ -399,27 +423,33 @@ class LM:
                 add_aux(aux_c)
             elif mode == "prefill":
                 def body(xx, pl, _kind=kind):
-                    y, nc, _ = apply_block(
+                    y, nc, aux = apply_block(
                         _kind, xx, pl, cfg, plan, mode="prefill",
                         cache="init", positions=positions,
                         mrope_positions=mrope_positions, enc_out=enc_out)
-                    return y, nc
-                x, ncs = lax.scan(body, x, stack)
+                    return y, (nc, aux.get("moe_hits"))
+                x, (ncs, h) = lax.scan(body, x, stack)
                 new_caches.setdefault(kind, []).append(ncs)
+                if h is not None:
+                    hits.append(h)
             else:  # decode
                 def body(carry, pi, _kind=kind):
                     xx, cs = carry
                     pl, li = pi
-                    y, cs, _ = apply_block(
+                    y, cs, aux = apply_block(
                         _kind, xx, pl, cfg, plan, mode="decode",
                         cache=cs, positions=positions, pos_offset=pos_offset,
                         mrope_positions=mrope_positions, enc_out=enc_out,
                         layer=li)
-                    return (y, cs), None
+                    return (y, cs), aux.get("moe_hits")
                 layer_ids = start + jnp.arange(count, dtype=jnp.int32)
-                (x, caches[kind]), _ = lax.scan(body, (x, caches[kind]),
+                (x, caches[kind]), h = lax.scan(body, (x, caches[kind]),
                                                 (stack, layer_ids))
+                if h is not None:
+                    hits.append(h)
 
+        if hits:
+            aux_tot["moe_hits"] = jnp.concatenate(hits, 0)
         if mode == "decode":
             return x, caches, aux_tot
         # prefill: concatenate per-kind cache pieces into full stacks
@@ -505,9 +535,11 @@ class LM:
         return loss, {"ce": loss}
 
     # -- serving -----------------------------------------------------------------
-    def prefill(self, params, batch, plan, cache_len: Optional[int] = None):
+    def prefill(self, params, batch, plan, cache_len: Optional[int] = None,
+                stats: bool = False):
         """Process the prompt; returns (last-position logits (B,1,V) vocab-
-        sharded, caches padded to ``cache_len``)."""
+        sharded, caches padded to ``cache_len``), and with ``stats`` a dict
+        of the step's counts as well (``moe_hits``, see _run_segments)."""
         cfg = self.cfg
         self._plan = plan
         if cache_len is not None:
@@ -525,9 +557,9 @@ class LM:
             x = self._embed_in(params, batch, plan)
         positions = jnp.broadcast_to(jnp.arange(S)[None], (B, S))
         mrope = batch.get("mrope_positions") if cfg.mrope else None
-        x, caches, _ = self._run_segments(params, x, mode="prefill",
-                                          positions=positions,
-                                          mrope_positions=mrope)
+        x, caches, aux = self._run_segments(params, x, mode="prefill",
+                                            positions=positions,
+                                            mrope_positions=mrope)
         x = apply_norm(x, params["final_norm"], cfg.norm)
         x_last = x[:, -1:]
         unemb = params["embed"].get("unemb")
@@ -535,6 +567,8 @@ class LM:
             unemb = params["embed"]["emb"].T
         logits = jnp.einsum("bsd,dv->bsv", x_last, unemb)
         logits = plan.constrain(logits, "batch", None, "tp")
+        if stats:
+            return logits, caches, _step_stats(aux)
         return logits, caches
 
     def _encdec_prefill(self, params, batch, plan):
@@ -562,9 +596,11 @@ class LM:
         logits = jnp.einsum("bsd,dv->bsv", x[:, -1:], unemb)
         return plan.constrain(logits, "batch", None, "tp"), caches
 
-    def decode_step(self, params, caches, batch, plan):
+    def decode_step(self, params, caches, batch, plan, stats: bool = False):
         """One token for every sequence.  batch: {'token': (B,1), 'pos': ()}.
-        Returns (logits (B,1,V) vocab-sharded, new caches)."""
+        Returns (logits (B,1,V) vocab-sharded, new caches), and with
+        ``stats`` a dict of the step's counts as well (``moe_hits``, see
+        _run_segments)."""
         cfg = self.cfg
         self._plan = plan
         tok = batch["token"]
@@ -582,7 +618,7 @@ class LM:
         mrope = batch.get("mrope_positions") if cfg.mrope else None
         cache_pos = self._cache_write_pos(pos)
         segs = [("dec", cfg.dec_layers)] if cfg.family == "encdec" else None
-        x, new_caches, _ = self._run_segments(
+        x, new_caches, aux = self._run_segments(
             params, x, mode="decode", caches=caches, positions=positions,
             pos_offset=cache_pos, mrope_positions=mrope, segments=segs)
         x = apply_norm(x, params["final_norm"], cfg.norm)
@@ -591,6 +627,8 @@ class LM:
             unemb = params["embed"]["emb"].T
         logits = jnp.einsum("bsd,dv->bsv", x, unemb)
         logits = plan.constrain(logits, "batch", None, "tp")
+        if stats:
+            return logits, new_caches, _step_stats(aux)
         return logits, new_caches
 
     def _cache_write_pos(self, pos):

@@ -15,6 +15,19 @@ Two lowerings, chosen per architecture:
   mode='tp'  (E < tp, e.g. mixtral 8e): experts replicated, expert FFN
              tensor-parallel over the model axis; tokens gathered over the
              model axis for dispatch, outputs reduce-scattered back.
+
+A layer that holds only a share of the experts (``n_experts_held``: one
+chip's experts of a deployment that spreads each layer over many) routes
+over all ``n_experts`` and computes, for every token, what its own experts
+give: each held expert runs on the layer's tokens and the router's weights
+combine them, so no token is dropped.  The absent experts' part is left to
+the chips that hold them (it is not computed here), the shared expert is
+added once.
+
+The router scores by softmax or, for DeepSeek-V3's ``noaux_tc``, by a
+sigmoid with a per-expert correction bias that takes part in picking the
+top-k but not in weighting them; the picked scores are normalized and
+scaled by ``routed_scale``.
 """
 
 from __future__ import annotations
@@ -38,13 +51,18 @@ def moe_defs(cfg, layers: Optional[int] = None):
     E, dff = cfg.n_experts, cfg.moe_d_ff
     ex_ax = "expert" if cfg.moe_mode == "ep" else None
     ff_ax = None if cfg.moe_mode == "ep" else "tp"
+    if cfg.n_experts_held:              # this chip's share, all local
+        E, ex_ax, ff_ax = cfg.n_experts_held, None, None
     d = {
-        "router": ParamDef(lead + (cfg.d_model, E), la + ("fsdp", None),
-                           dtype=jnp.float32),
+        "router": ParamDef(lead + (cfg.d_model, cfg.n_experts),
+                           la + ("fsdp", None), dtype=jnp.float32),
         "wi": ParamDef(lead + (E, cfg.d_model, dff), la + (ex_ax, "fsdp", ff_ax)),
         "wg": ParamDef(lead + (E, cfg.d_model, dff), la + (ex_ax, "fsdp", ff_ax)),
         "wo": ParamDef(lead + (E, dff, cfg.d_model), la + (ex_ax, ff_ax, "fsdp")),
     }
+    if cfg.router_bias:
+        d["router_bias"] = ParamDef(lead + (cfg.n_experts,), la + (None,),
+                                    dtype=jnp.float32, init="zeros")
     if cfg.n_shared_experts:
         sff = cfg.moe_d_ff * cfg.n_shared_experts
         d["shared"] = {
@@ -55,12 +73,22 @@ def moe_defs(cfg, layers: Optional[int] = None):
     return d
 
 
-def _route(x2d, wr, top_k: int):
-    """Router: returns (probs(T,E) f32, topk_w(T,K), topk_idx(T,K), aux)."""
+def _route(x2d, wr, top_k: int, score: str = "softmax", bias=None,
+           scale: float = 1.0):
+    """Router: returns (probs(T,E) f32, topk_w(T,K), topk_idx(T,K), aux).
+    ``bias`` (E,) is added to the scores to pick the top-k only."""
     logits = x2d.astype(jnp.float32) @ wr.astype(jnp.float32)
-    probs = jax.nn.softmax(logits, axis=-1)
-    topk_w, topk_idx = lax.top_k(probs, top_k)
+    if score == "sigmoid":
+        scores = jax.nn.sigmoid(logits)
+        probs = scores / jnp.maximum(scores.sum(-1, keepdims=True), 1e-9)
+    else:
+        scores = probs = jax.nn.softmax(logits, axis=-1)
+    pick = scores if bias is None else scores + bias.astype(jnp.float32)
+    _, topk_idx = lax.top_k(pick, top_k)
+    topk_w = jnp.take_along_axis(scores, topk_idx, -1)
     topk_w = topk_w / jnp.maximum(topk_w.sum(-1, keepdims=True), 1e-9)
+    if scale != 1.0:
+        topk_w = topk_w * scale
     # switch-style load-balance loss + router z-loss
     E = probs.shape[-1]
     me = probs.mean(0)                                     # (E,)
@@ -101,8 +129,43 @@ def _glu(h, wi, wg, wo):
     return jnp.einsum("ecf,efd->ecd", a * g, wo)
 
 
+def _held_block(x, p, cfg, plan):
+    """The held experts' part for every token: (out, lb, z, hits) with
+    ``hits`` (T, n_experts_held) int32, 1 where the token picked the
+    expert."""
+    B, S, d = x.shape
+    held, off = cfg.n_experts_held, cfg.expert_offset
+    x2 = x.reshape(B * S, d)
+    router = plan.gather_fsdp(p["router"], ("fsdp", None))
+    _, tw, ti, aux = _route(x2, router, cfg.top_k, cfg.router_score,
+                            p.get("router_bias"), cfg.routed_scale)
+    pick = ti[:, :, None] == off + jnp.arange(held)[None, None, :]
+    gate = jnp.einsum("tk,tke->te", tw, pick.astype(jnp.float32))
+    wi = plan.gather_fsdp(p["wi"], (None, "fsdp", None))
+    wg = plan.gather_fsdp(p["wg"], (None, "fsdp", None))
+    wo = plan.gather_fsdp(p["wo"], (None, None, "fsdp"))
+    a = jnp.einsum("td,edf->etf", x2, wi)
+    g = jax.nn.silu(jnp.einsum("td,edf->etf", x2, wg))
+    y = jnp.einsum("etf,efd->etd", a * g, wo)             # (held, T, d)
+    out = jnp.einsum("etd,te->td", y.astype(jnp.float32), gate)
+    return (out.reshape(B, S, d).astype(x.dtype), aux["lb"], aux["z"],
+            pick.any(1).astype(jnp.int32))
+
+
 def moe_block(x, p, cfg, plan):
-    """x: (B, S, d) sharded (batch, sp, -). Returns (out, aux_losses)."""
+    """x: (B, S, d) sharded (batch, sp, -). Returns (out, aux) where aux
+    holds the router's losses and, for a layer that holds a share of the
+    experts, ``moe_hits``: (B*S, n_experts_held) int32, which tokens each
+    held expert took."""
+    with jax.named_scope("moe"):
+        return _moe_block(x, p, cfg, plan)
+
+
+def _moe_block(x, p, cfg, plan):
+    if cfg.n_experts_held:
+        out, lb, z, hits = _held_block(x, p, cfg, plan)
+        return _add_shared(out, x, p, cfg), {"moe_lb": lb, "moe_z": z,
+                                             "moe_hits": hits}
     mesh = plan.mesh
     B, S, _d = x.shape
     batch_ax = plan._fit_dim(B, "batch")
@@ -127,10 +190,13 @@ def moe_block(x, p, cfg, plan):
                  P(None, "model", None))
         wspec_i, wspec_g, wspec_o = wspec
 
-    def ep_body(xl, wr, wi, wg, wo):
+    def route(x2, wr, bias):
+        return _route(x2, wr, K, cfg.router_score, bias, cfg.routed_scale)
+
+    def ep_body(xl, wr, bias, wi, wg, wo):
         Bl, Sl, d = xl.shape
         x2 = xl.reshape(Bl * Sl, d)
-        probs, tw, ti, aux = _route(x2, wr, K)
+        probs, tw, ti, aux = route(x2, wr, bias)
         C = expert_capacity(Bl * Sl, E, K, cfg.capacity_factor)
         buf, slot, keep = _dispatch_local(x2, ti, tw, E, C)
         # MPMC: token shards -> expert shards
@@ -144,14 +210,14 @@ def moe_block(x, p, cfg, plan):
         aux = {k: _pmean(v) for k, v in aux.items()}
         return out, aux["lb"], aux["z"]
 
-    def tp_body(xl, wr, wi, wg, wo):
+    def tp_body(xl, wr, bias, wi, wg, wo):
         # tokens gathered over model axis; expert FFN is ff-sharded
         Bl, Sl, d = xl.shape
         xg = lax.all_gather(xl, model_ax, axis=1, tiled=True) \
             if seq_sharded else xl                              # (B, S, d)
         Sg = xg.shape[1]
         x2 = xg.reshape(Bl * Sg, d)
-        probs, tw, ti, aux = _route(x2, wr, K)
+        probs, tw, ti, aux = route(x2, wr, bias)
         C = expert_capacity(Bl * Sg, E, K, cfg.capacity_factor)
         buf, slot, keep = _dispatch_local(x2, ti, tw, E, C)
         y = _glu(buf, wi, wg, wo)                               # partial (ff shard)
@@ -168,11 +234,11 @@ def moe_block(x, p, cfg, plan):
 
     body = ep_body if cfg.moe_mode == "ep" else tp_body
     if cfg.moe_mode == "ep":
-        in_specs = (xspec, rspec, wspec, wspec, wspec)
+        in_specs = (xspec, rspec, P(None), wspec, wspec, wspec)
         wax = ("expert", "fsdp", None)
         oax = ("expert", None, "fsdp")
     else:
-        in_specs = (xspec, rspec, wspec_i, wspec_g, wspec_o)
+        in_specs = (xspec, rspec, P(None), wspec_i, wspec_g, wspec_o)
         wax = (None, "fsdp", "tp")
         oax = (None, "tp", "fsdp")
     # gather the bf16 expert weights over the fsdp axis *before* the
@@ -185,14 +251,15 @@ def moe_block(x, p, cfg, plan):
     fn = shard_map(body, mesh=mesh,
                    in_specs=in_specs,
                    out_specs=(xspec, P(), P()), check_vma=False)
-    out, lb, z = fn(x, router, wi, wg, wo)
+    out, lb, z = fn(x, router, p.get("router_bias"), wi, wg, wo)
+    return _add_shared(out, x, p, cfg), {"moe_lb": lb, "moe_z": z}
 
+
+def _add_shared(out, x, p, cfg):
     if cfg.n_shared_experts:
         sp_ = p["shared"]
         a = jnp.einsum("bsd,df->bsf", x, sp_["wi"])
         g = jax.nn.silu(jnp.einsum("bsd,df->bsf", x, sp_["wg"]))
         out = out + jnp.einsum("bsf,fd->bsd", a * g, sp_["wo"],
-                                preferred_element_type=jnp.bfloat16)
-
-    aux = {"moe_lb": lb, "moe_z": z}
-    return out, aux
+                                preferred_element_type=x.dtype)
+    return out

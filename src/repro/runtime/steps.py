@@ -163,24 +163,32 @@ def make_train_step(cfg: Config, plan: ShardingPlan, lr_fn: Callable,
     return train_step
 
 
-def make_prefill_step(cfg: Config, plan: ShardingPlan, cache_len: int):
+def make_prefill_step(cfg: Config, plan: ShardingPlan, cache_len: int,
+                      stats: bool = False):
+    """prefill_step(params, batch) -> (logits, caches), and the step's
+    counts (``LM.prefill``) last with ``stats``."""
     model = LM(cfg)
 
     def prefill_step(params, batch):
-        return model.prefill(params, batch, plan, cache_len=cache_len)
+        return model.prefill(params, batch, plan, cache_len=cache_len,
+                             stats=stats)
 
     return prefill_step
 
 
-def make_decode_step(cfg: Config, plan: ShardingPlan, cache_len: int):
+def make_decode_step(cfg: Config, plan: ShardingPlan, cache_len: int,
+                     stats: bool = False):
+    """decode_step(params, caches, batch) -> (next tokens (B, 1), logits,
+    caches), and the step's counts (``LM.decode_step``) last with
+    ``stats``."""
     model = LM(cfg)
     cfg.cache_len = (min(cache_len, cfg.window) if cfg.attn_kind == "swa"
                      else cache_len)
 
     def decode_step(params, caches, batch):
-        logits, new_caches = model.decode_step(params, caches, batch, plan)
+        out = model.decode_step(params, caches, batch, plan, stats=stats)
         # greedy token for the feedback loop (argmax over vocab-sharded dim)
-        next_tok = jnp.argmax(logits[:, -1, :], axis=-1).astype(jnp.int32)
-        return next_tok[:, None], logits, new_caches
+        next_tok = jnp.argmax(out[0][:, -1, :], axis=-1).astype(jnp.int32)
+        return (next_tok[:, None],) + out
 
     return decode_step

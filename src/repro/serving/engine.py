@@ -220,6 +220,39 @@ class _Ready:
     done_t: float = 0.0         # when the prefill finished on the device
 
 
+class _ExpertCounts:
+    """What the held experts of a model's expert layers took: each step
+    hands back the tokens routed to each held expert of each expert layer
+    (``LM.decode_step``'s ``moe_hits``, summed over the step's live
+    tokens).  Per phase (``decode``, ``prefill``):
+    ``moe_assignments``, the routed slots of every token (tokens x top_k x
+    expert layers); ``moe_local_assignments``, those on held experts;
+    ``moe_experts_touched``, held experts of a layer with a token, summed
+    over layers and steps; ``steps``."""
+
+    KEYS = ("moe_assignments", "moe_local_assignments",
+            "moe_experts_touched", "steps")
+
+    def __init__(self, cfg):
+        self.layers = cfg.stack_sizes().get("moe", 0)
+        self.top_k = cfg.top_k
+        self._lock = threading.Lock()
+        self._by_phase = {p: dict.fromkeys(self.KEYS, 0)
+                          for p in ("decode", "prefill")}
+
+    def add(self, phase: str, counts: np.ndarray, tokens: int) -> None:
+        with self._lock:
+            d = self._by_phase[phase]
+            d["moe_assignments"] += int(tokens) * self.top_k * self.layers
+            d["moe_local_assignments"] += int(counts.sum())
+            d["moe_experts_touched"] += int((counts > 0).sum())
+            d["steps"] += 1
+
+    def snapshot(self) -> dict:
+        with self._lock:
+            return {p: dict(d) for p, d in self._by_phase.items()}
+
+
 class _BatchState:
     """The batched decode state: KV caches for B slots + bookkeeping.
     Owned by whichever node currently holds the tick."""
@@ -253,11 +286,13 @@ class PrefillNode(FFNode):
     the downstream SPSC lane still sees serialized pushes — the same
     discipline ``HostRunner`` uses on its multi-producer input queue."""
 
-    def __init__(self, prefill, params, n_workers: int = 2):
+    def __init__(self, prefill, params, n_workers: int = 2,
+                 counts: Optional[_ExpertCounts] = None):
         super().__init__()
         self._label = "prefill-farm"
         self._prefill = prefill
         self._params = params
+        self._counts = counts
         self.n_workers = max(1, n_workers)
         self._jobs: "queue.Queue[Any]" = queue.Queue()
         self._emit_lock = threading.Lock()
@@ -281,10 +316,14 @@ class PrefillNode(FFNode):
                                      len=len(req.prompt),
                                      queued_us=queued_us):
                     prompt = jnp.asarray(req.prompt, jnp.int32)[None, :]
-                    tok, cache1 = self._prefill(self._params, prompt)
+                    tok, cache1, *hits = self._prefill(self._params,
+                                                       prompt)
                 tok.block_until_ready()
                 out = _Ready(req, tok, cache1, int(prompt.shape[1]),
                              done_t=time.perf_counter())
+                if hits:
+                    self._counts.add("prefill", np.asarray(hits[0]),
+                                     out.prompt_len)
                 with self._stats_lock:
                     self.prefills += 1
             except Exception as e:  # noqa: BLE001 - re-raised by the handle
@@ -460,12 +499,14 @@ class DecodeNode(FFNode):
     for the early-exit policy.  Non-tick items (``Deliver`` escapes from
     upstream) pass straight through."""
 
-    def __init__(self, state: _BatchState, params, decode):
+    def __init__(self, state: _BatchState, params, decode,
+                 counts: bool = False):
         super().__init__()
         self._label = "decode"
         self.state = state
         self.params = params
         self._decode = decode
+        self._counts = counts       # the step counts the held experts' tokens
         self.steps = 0
 
     def svc(self, item):
@@ -475,10 +516,15 @@ class DecodeNode(FFNode):
         step = self.steps
         with TraceAnnotation(SPAN_DECODE, step=step,
                              batch=int(st.active_mask.sum())):
-            nt, conf, st.caches = self._decode(
-                self.params, st.caches, {"token": st.cur_tok, "pos": st.pos})
+            batch = {"token": st.cur_tok, "pos": st.pos}
+            if self._counts:      # the counts leave idle slots' rows out
+                batch["active"] = jnp.asarray(st.active_mask, jnp.int32)
+            nt, conf, st.caches, *col = self._decode(self.params, st.caches,
+                                                     batch)
             st.cur_tok = nt
-            st.pos = st.pos + jnp.asarray(st.active_mask, jnp.int32)
+            col = col[0] if col else nt     # the column the collector reads
+            st.pos = st.pos + (batch["active"] if self._counts else
+                               jnp.asarray(st.active_mask, jnp.int32))
             self.steps += 1
             # the overlapped boundary, serving edition: do NOT sync here —
             # start the device->host copies and hand the unfinalized arrays
@@ -486,14 +532,14 @@ class DecodeNode(FFNode):
             # compute remainder) rides under the decode->collect hop, and the
             # next tick's CacheManager refill dispatches behind the in-flight
             # step without a host sync in between
-            for leaf in (nt, conf):
+            for leaf in (col, conf):
                 copy = getattr(leaf, "copy_to_host_async", None)
                 if copy is not None:
                     try:
                         copy()
                     except Exception:   # noqa: BLE001 - optional fast path
                         pass
-        st.pending = (step, nt, conf)
+        st.pending = (step, col, conf)
         return _TICK
 
 
@@ -506,9 +552,11 @@ class CollectNode(FFNode):
     def __init__(self, state: _BatchState, cm: CacheManager,
                  acct: _Accounting, slo: _SLOState,
                  eos_token: Optional[int],
-                 exit_threshold: Optional[float]):
+                 exit_threshold: Optional[float],
+                 counts: Optional[_ExpertCounts] = None):
         super().__init__()
         self._label = "collect"
+        self._counts = counts
         self.state = state
         self.cm = cm
         self.acct = acct
@@ -568,14 +616,18 @@ class CollectNode(FFNode):
         if item is not _TICK:
             return item                   # pass-through
         st = self.state
-        step = -1
+        step, counts = -1, None
         if st.pending is not None:        # resolve the in-flight decode step
-            step, nt, conf = st.pending
+            step, col, conf = st.pending
             st.pending = None
             with TraceAnnotation(SPAN_SYNC, step=step):
-                st.last_toks = np.asarray(nt[:, 0])
+                col = np.asarray(col[:, 0])
                 st.last_conf = np.asarray(conf)
+            # B tokens, then the held experts' counts where the step has them
+            st.last_toks, counts = col[:self.cm.B], col[self.cm.B:]
         with TraceAnnotation(SPAN_COLLECT, step=step) as span:
+            if counts is not None and self._counts is not None:
+                self._counts.add("decode", counts, int(st.active_mask.sum()))
             span.set_metadata(finished=self._collect(st))
         return _TICK                      # wrap_around -> loop head
 
@@ -603,25 +655,40 @@ class InferenceEngine:
         # under any offered load
         self.max_pending = max_pending
 
-        prefill_step = make_prefill_step(cfg, plan, cache_len)
+        # a model whose expert layers hold a share of the experts counts,
+        # in every step, the tokens each held expert took: the decode step
+        # puts its counts after its tokens in the one column it copies out
+        self._counts = _ExpertCounts(cfg) if cfg.n_experts_held else None
+        counts = self._counts is not None
+        prefill_step = make_prefill_step(cfg, plan, cache_len, stats=counts)
+        decode_step = make_decode_step(cfg, plan, cache_len, stats=counts)
+
+        def _held_counts(out, live=None):
+            hits = out[-1]["moe_hits"]              # (layers, T, held)
+            if live is not None:
+                hits = hits * live[None, :, None]
+            return hits.sum(1).reshape(-1)
 
         def _prefill(p, tokens):
-            logits, cache1 = prefill_step(p, {"tokens": tokens})
-            tok = jnp.argmax(logits[:, -1], -1).astype(jnp.int32)[:, None]
-            return tok, cache1
-
-        decode_step = make_decode_step(cfg, plan, cache_len)
+            out = prefill_step(p, {"tokens": tokens})
+            tok = jnp.argmax(out[0][:, -1], -1).astype(jnp.int32)[:, None]
+            return (tok, out[1]) + ((_held_counts(out),) if counts else ())
 
         def _decode(p, caches, batch):
-            nt, logits, caches = decode_step(p, caches, batch)
+            out = decode_step(p, caches, batch)
+            nt, logits, caches = out[:3]
             conf = jnp.max(jax.nn.softmax(logits[:, -1, :], axis=-1), -1)
-            return nt, conf, caches
+            if not counts:
+                return nt, conf, caches
+            hits = _held_counts(out, batch["active"])
+            return nt, conf, caches, jnp.concatenate([nt, hits[:, None]])
 
         self._acct = _Accounting()
         self._slo = _SLOState(slo or SLOPolicy())
         self.state = _BatchState(cfg, self.B, cache_len)
         self._prefill_node = PrefillNode(jax.jit(_prefill), params,
-                                         n_workers=prefill_workers)
+                                         n_workers=prefill_workers,
+                                         counts=self._counts)
         self._cm = CacheManager(self.state, self.B,
                                 jax.jit(self._insert_impl,
                                         donate_argnums=0), self._acct,
@@ -629,9 +696,11 @@ class InferenceEngine:
         # the batched caches are donated to the insert and decode steps: the
         # chip holds one copy of them, updated in place
         self._decode_node = DecodeNode(self.state, params,
-                                       jax.jit(_decode, donate_argnums=1))
+                                       jax.jit(_decode, donate_argnums=1),
+                                       counts=self._counts is not None)
         self._collect = CollectNode(self.state, self._cm, self._acct,
-                                    self._slo, eos_token, exit_threshold)
+                                    self._slo, eos_token, exit_threshold,
+                                    self._counts)
 
         self.graph = pipeline(self._prefill_node, self._cm,
                               self._decode_node,
@@ -684,6 +753,8 @@ class InferenceEngine:
                          "finished": self._acct.finished,
                          "shed": self._acct.shed,
                          "failed": self._acct.failed}
+        if self._counts is not None:
+            s["moe"] = self._counts.snapshot()
         if self.supervisor is not None:
             s["supervisor"] = self.supervisor.stats()
         return s
