@@ -94,3 +94,15 @@ def router_topk_ref(logits, top_k: int, capacity: int):
     keep = pos < capacity
     return (w, idx, pos.reshape(T, top_k).astype(jnp.int32),
             keep.reshape(T, top_k))
+
+
+def grouped_matmul_ref(lhs, rhs, sizes):
+    """Each row of ``lhs`` times its group's matrix of ``rhs``: rows sorted
+    by group, ``sizes[g]`` rows in group g; rows past ``sizes.sum()`` give
+    0.  lhs (m, k), rhs (G, k, n), sizes (G,) -> (m, n) in lhs.dtype."""
+    G = rhs.shape[0]
+    group = jnp.searchsorted(jnp.cumsum(sizes), jnp.arange(lhs.shape[0]),
+                             side="right")                       # (m,)
+    w = rhs[jnp.minimum(group, G - 1)].astype(jnp.float32)       # (m, k, n)
+    out = jnp.einsum("mk,mkn->mn", lhs.astype(jnp.float32), w)
+    return jnp.where((group < G)[:, None], out, 0.0).astype(lhs.dtype)

@@ -21,10 +21,12 @@ Sharding: only logical axes (core/plan.py).  Embedding and cross-entropy are
 vocab-parallel (Megatron-style shard_map) so full logits are never
 materialized.  The layer scans are the *only* ``while`` loops in any step
 function — launch/dryrun.py relies on this for exact loop-corrected cost
-accounting.  Decode keeps it so: each layer stores its new K/V rows with
-one batched scatter into the carried cache, which the TPU compiler emits
-as an in-place fusion, not a loop (tests/test_tpu_compile.py counts the
-loops of a compiled decode step).
+accounting — but for one inside an expert layer that holds a share of the
+experts (the grouped matmul's group bookkeeping).  Decode keeps it so:
+each layer stores its new K/V rows with one batched scatter into the
+carried cache, which the TPU compiler emits as an in-place fusion, not a
+loop (tests/test_tpu_compile.py counts the loops of a compiled decode
+step).
 """
 
 from __future__ import annotations
@@ -43,7 +45,7 @@ from .attention import attention, attn_defs, cross_attention, cross_kv
 from .layers import apply_norm, mlp, mlp_defs, norm_defs
 from .mla import CACHE_AXES as MLA_CACHE_AXES
 from .mla import cache_width, mla_attention, mla_defs
-from .moe import moe_block, moe_defs
+from .moe import HELD_WEIGHTS, moe_block, moe_defs
 from .params import ParamDef, init_params, shape_structs
 from .ssm import mamba2_block, mamba2_defs, mamba2_state_defs
 from .xlstm import (mlstm_block, mlstm_defs, mlstm_state_defs, slstm_block,
@@ -146,7 +148,7 @@ def _residual(x, delta, plan):
 
 def dense_block(x, p, cfg, plan, *, mode, cache=None, positions=None,
                 pos_offset=0, mrope_positions=None, causal=True,
-                window=0, moe=False, layer=None):
+                window=0, moe=False, layer=None, active=None):
     aux = {}
     xn = apply_norm(x, p["ln1"], cfg.norm)
     if cfg.attn_kind == "mla":
@@ -163,7 +165,7 @@ def dense_block(x, p, cfg, plan, *, mode, cache=None, positions=None,
     x = _residual(x, a, plan)
     xn = apply_norm(x, p["ln2"], cfg.norm)
     if moe:
-        m, aux = moe_block(xn, p["moe"], cfg, plan)
+        m, aux = moe_block(xn, p["moe"], cfg, plan, active, layer)
     else:
         m = mlp(xn, p["mlp"], cfg.act, plan)
     x = _residual(x, m, plan)
@@ -191,20 +193,24 @@ def _layer_of(stack, layer):
 
 
 def apply_block(kind, x, p, cfg, plan, *, mode, cache=None, positions=None,
-                pos_offset=0, mrope_positions=None, enc_out=None, layer=None):
+                pos_offset=0, mrope_positions=None, enc_out=None, layer=None,
+                active=None):
     """Uniform block dispatch. Returns (x, new_cache, aux).
 
     In decode ``cache`` is the kind's whole stacked cache and ``layer`` the
     block's index in it; the stack comes back with only this layer's new
     entries written: the new K/V rows of an append-only cache, the whole
     (small) state of a recurrent block, nothing of a read-only cross cache.
+    ``active`` (B,) marks the live rows, for an expert layer that holds a
+    share of the experts.
     """
     window = cfg.window if cfg.attn_kind == "swa" else 0
     if kind in ("dense", "moe"):
         return dense_block(x, p, cfg, plan, mode=mode, cache=cache,
                            positions=positions, pos_offset=pos_offset,
                            mrope_positions=mrope_positions, layer=layer,
-                           causal=True, window=window, moe=(kind == "moe"))
+                           causal=True, window=window, moe=(kind == "moe"),
+                           active=active)
     if kind == "shared_attn":
         return dense_block(x, p, cfg, plan, mode=mode, cache=cache,
                            positions=positions, pos_offset=pos_offset,
@@ -309,8 +315,12 @@ def _cache_struct(kind, cfg, B, S_max, layers):
     raise ValueError(kind)
 
 
+# what a step reports of its expert layers (``moe_block``), per layer
+STEP_STATS = ("moe_hits", "moe_group_sizes")
+
+
 def _step_stats(aux) -> Dict[str, Any]:
-    return {"moe_hits": aux["moe_hits"]} if "moe_hits" in aux else {}
+    return {k: aux[k] for k in STEP_STATS if k in aux}
 
 
 # ---------------------------------------------------------------------------
@@ -349,7 +359,7 @@ class LM:
     # -- segment runner ---------------------------------------------------------
     def _run_segments(self, params, x, *, mode, caches=None, positions=None,
                       pos_offset=0, mrope_positions=None, enc_out=None,
-                      segments=None):
+                      segments=None, active=None):
         """Run the segment list; returns (x, new_caches, aux_sum).
 
         Decode carries each kind's whole stacked cache through its layer
@@ -358,15 +368,17 @@ class LM:
         writes its new entries into the donated buffer in place.
 
         In prefill and decode, an expert layer that holds a share of the
-        experts reports which tokens each held expert took: ``aux_sum``
-        then holds ``moe_hits``, (MoE layers, tokens, held) int32."""
+        experts reports which live tokens each held expert took: ``aux_sum``
+        then holds ``moe_hits``, (MoE layers, tokens, held) int32, and
+        ``moe_group_sizes``, (MoE layers, held) int32, its sums over the
+        tokens.  ``active`` (B,) marks a decode step's live rows."""
         cfg = self.cfg
         segments = segments if segments is not None else cfg.segments
         offsets = {k: 0 for k, _ in segments}
         shared_i = 0
         aux_tot = {}
         new_caches: Dict[str, Any] = {}
-        hits = []
+        stats = []
         if mode == "decode":
             caches = dict(caches)
 
@@ -403,6 +415,20 @@ class LM:
             stack = jax.tree.map(lambda t: t[start:start + count],
                                  params["stacks"][kind])
             plan = self._plan
+            # a held expert share's matrices stay whole: each layer's block
+            # gets the kind's stack and its index in it, for the grouped
+            # matmul to read them in place (a layer's slice would be copied)
+            experts = None
+            if kind == "moe" and cfg.n_experts_held and mode != "train":
+                experts = {k: params["stacks"][kind]["moe"][k]
+                           for k in HELD_WEIGHTS}
+                stack = dict(stack, moe={k: v for k, v in stack["moe"].items()
+                                         if k not in experts})
+
+            def whole(pl, _experts=experts):
+                if _experts is None:
+                    return pl
+                return dict(pl, moe=dict(pl["moe"], **_experts))
 
             if mode == "train":
                 def body(carry, pl, _kind=kind):
@@ -422,34 +448,38 @@ class LM:
                 (x, aux_c), _ = lax.scan(body, (x, aux0), stack)
                 add_aux(aux_c)
             elif mode == "prefill":
-                def body(xx, pl, _kind=kind):
+                def body(xx, pi, _kind=kind):
+                    pl, li = pi
                     y, nc, aux = apply_block(
-                        _kind, xx, pl, cfg, plan, mode="prefill",
+                        _kind, xx, whole(pl), cfg, plan, mode="prefill",
                         cache="init", positions=positions,
-                        mrope_positions=mrope_positions, enc_out=enc_out)
-                    return y, (nc, aux.get("moe_hits"))
-                x, (ncs, h) = lax.scan(body, x, stack)
+                        mrope_positions=mrope_positions, enc_out=enc_out,
+                        layer=li)
+                    return y, (nc, _step_stats(aux))
+                layer_ids = (start + jnp.arange(count, dtype=jnp.int32)
+                             if experts else None)
+                x, (ncs, st) = lax.scan(body, x, (stack, layer_ids))
                 new_caches.setdefault(kind, []).append(ncs)
-                if h is not None:
-                    hits.append(h)
+                stats.append(st)
             else:  # decode
                 def body(carry, pi, _kind=kind):
                     xx, cs = carry
                     pl, li = pi
                     y, cs, aux = apply_block(
-                        _kind, xx, pl, cfg, plan, mode="decode",
+                        _kind, xx, whole(pl), cfg, plan, mode="decode",
                         cache=cs, positions=positions, pos_offset=pos_offset,
                         mrope_positions=mrope_positions, enc_out=enc_out,
-                        layer=li)
-                    return (y, cs), aux.get("moe_hits")
+                        layer=li, active=active)
+                    return (y, cs), _step_stats(aux)
                 layer_ids = start + jnp.arange(count, dtype=jnp.int32)
-                (x, caches[kind]), h = lax.scan(body, (x, caches[kind]),
-                                                (stack, layer_ids))
-                if h is not None:
-                    hits.append(h)
+                (x, caches[kind]), st = lax.scan(body, (x, caches[kind]),
+                                                 (stack, layer_ids))
+                stats.append(st)
 
-        if hits:
-            aux_tot["moe_hits"] = jnp.concatenate(hits, 0)
+        for k in STEP_STATS:
+            per_seg = [st[k] for st in stats if k in st]
+            if per_seg:
+                aux_tot[k] = jnp.concatenate(per_seg, 0)
         if mode == "decode":
             return x, caches, aux_tot
         # prefill: concatenate per-kind cache pieces into full stacks
@@ -539,7 +569,8 @@ class LM:
                 stats: bool = False):
         """Process the prompt; returns (last-position logits (B,1,V) vocab-
         sharded, caches padded to ``cache_len``), and with ``stats`` a dict
-        of the step's counts as well (``moe_hits``, see _run_segments)."""
+        of the step's counts as well (``moe_hits``, ``moe_group_sizes``, see
+        _run_segments)."""
         cfg = self.cfg
         self._plan = plan
         if cache_len is not None:
@@ -597,10 +628,12 @@ class LM:
         return plan.constrain(logits, "batch", None, "tp"), caches
 
     def decode_step(self, params, caches, batch, plan, stats: bool = False):
-        """One token for every sequence.  batch: {'token': (B,1), 'pos': ()}.
-        Returns (logits (B,1,V) vocab-sharded, new caches), and with
-        ``stats`` a dict of the step's counts as well (``moe_hits``, see
-        _run_segments)."""
+        """One token for every sequence.  batch: {'token': (B,1), 'pos': ()
+        or (B,)}, and optionally 'active': (B,) int32, the live slots (the
+        rest are left out of the held experts' work and counts).  Returns
+        (logits (B,1,V) vocab-sharded, new caches), and with ``stats`` a
+        dict of the step's counts as well (``moe_hits``,
+        ``moe_group_sizes``, see _run_segments)."""
         cfg = self.cfg
         self._plan = plan
         tok = batch["token"]
@@ -620,7 +653,8 @@ class LM:
         segs = [("dec", cfg.dec_layers)] if cfg.family == "encdec" else None
         x, new_caches, aux = self._run_segments(
             params, x, mode="decode", caches=caches, positions=positions,
-            pos_offset=cache_pos, mrope_positions=mrope, segments=segs)
+            pos_offset=cache_pos, mrope_positions=mrope, segments=segs,
+            active=batch.get("active"))
         x = apply_norm(x, params["final_norm"], cfg.norm)
         unemb = params["embed"].get("unemb")
         if unemb is None:

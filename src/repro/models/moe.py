@@ -19,7 +19,8 @@ Two lowerings, chosen per architecture:
 A layer that holds only a share of the experts (``n_experts_held``: one
 chip's experts of a deployment that spreads each layer over many) routes
 over all ``n_experts`` and computes, for every token, what its own experts
-give: each held expert runs on the layer's tokens and the router's weights
+give: each held expert runs on the tokens routed to it, as one group of a
+grouped matmul (``kernels/grouped_matmul.py``), and the router's weights
 combine them, so no token is dropped.  The absent experts' part is left to
 the chips that hold them (it is not computed here), the shared expert is
 added once.
@@ -42,6 +43,7 @@ from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from ..core.device import expert_capacity
+from ..kernels.grouped_matmul import grouped_matmul
 from .params import ParamDef
 
 
@@ -129,43 +131,89 @@ def _glu(h, wi, wg, wo):
     return jnp.einsum("ecf,efd->ecd", a * g, wo)
 
 
-def _held_block(x, p, cfg, plan):
-    """The held experts' part for every token: (out, lb, z, hits) with
-    ``hits`` (T, n_experts_held) int32, 1 where the token picked the
-    expert."""
+HELD_WEIGHTS = ("wi", "wg", "wo")
+
+
+def _held_block(x, p, cfg, plan, active=None, layer=None):
+    """The held experts' part for every live token, computed only for the
+    (token, held expert) pairs the router picked: the pairs are sorted by
+    expert, their rows gathered, each matrix run as one grouped matmul
+    whose groups are the experts' pairs (an expert with no pair reads none
+    of its weights), and each result added back to its token weighted by
+    the router.  No capacity, no drop: the buffer holds T x min(top_k,
+    held) rows, every pair a token can have, its tail unused.  ``active``
+    (B,) leaves out the rows of dead slots.
+
+    ``p``'s expert matrices are one layer's, (held, ...), or with
+    ``layer`` every layer's, (L, held, ...): the kernel then reads layer
+    ``layer``'s experts where they lie in the stack (the other layers'
+    groups are empty), where a slice of the stack would be a copy.
+
+    Returns (out, lb, z, hits, sizes): ``hits`` (T, held) int32, 1 where a
+    live token picked the expert, and ``sizes`` (held,) its column sums,
+    the group sizes the matmuls ran with."""
     B, S, d = x.shape
+    T, K = B * S, cfg.top_k
     held, off = cfg.n_experts_held, cfg.expert_offset
-    x2 = x.reshape(B * S, d)
+    R = min(K, held)
+    x2 = x.reshape(T, d)
     router = plan.gather_fsdp(p["router"], ("fsdp", None))
-    _, tw, ti, aux = _route(x2, router, cfg.top_k, cfg.router_score,
+    _, tw, ti, aux = _route(x2, router, K, cfg.router_score,
                             p.get("router_bias"), cfg.routed_scale)
-    pick = ti[:, :, None] == off + jnp.arange(held)[None, None, :]
-    gate = jnp.einsum("tk,tke->te", tw, pick.astype(jnp.float32))
-    wi = plan.gather_fsdp(p["wi"], (None, "fsdp", None))
-    wg = plan.gather_fsdp(p["wg"], (None, "fsdp", None))
-    wo = plan.gather_fsdp(p["wo"], (None, None, "fsdp"))
-    a = jnp.einsum("td,edf->etf", x2, wi)
-    g = jax.nn.silu(jnp.einsum("td,edf->etf", x2, wg))
-    y = jnp.einsum("etf,efd->etd", a * g, wo)             # (held, T, d)
-    out = jnp.einsum("etd,te->td", y.astype(jnp.float32), gate)
+    e = ti - off                                          # (T, K)
+    mine = (e >= 0) & (e < held)
+    if active is not None:
+        mine = mine & (jnp.repeat(active, S) > 0)[:, None]
+    hits = ((e[:, :, None] == jnp.arange(held)) & mine[:, :, None]).any(1)
+    hits = hits.astype(jnp.int32)
+    sizes = hits.sum(0)
+    # pairs sorted by held expert, the rest last: the first T*R are all
+    # the pairs there are
+    order = jnp.argsort(jnp.where(mine, e, held).reshape(-1), stable=True)
+    xs = x2[order[:T * R] // K]
+    if layer is None:
+        layer, p = 0, dict(p, **{k: p[k][None] for k in HELD_WEIGHTS})
+    L = p["wi"].shape[0]
+    groups = lax.dynamic_update_slice(jnp.zeros((L * held,), jnp.int32),
+                                      sizes, (layer * held,))
+
+    def stack(name, axes):
+        w = plan.gather_fsdp(p[name], (None, None) + axes)
+        return w.reshape((L * held,) + w.shape[2:])        # a view
+    a = grouped_matmul(xs, stack("wi", ("fsdp", None)), groups)
+    g = jax.nn.silu(grouped_matmul(xs, stack("wg", ("fsdp", None)), groups))
+    y = grouped_matmul(a * g, stack("wo", (None, "fsdp")), groups)  # (T*R, d)
+    # each pair's row in the sorted buffer; a pair not computed reads 0
+    row = jnp.zeros((T * K,), jnp.int32).at[order].set(
+        jnp.arange(T * K, dtype=jnp.int32)).reshape(T, K)
+    got = jnp.take(y, jnp.where(mine, row, T * R), axis=0, mode="fill",
+                   fill_value=0)                          # (T, K, d)
+    out = jnp.einsum("tkd,tk->td", got.astype(jnp.float32),
+                     jnp.where(mine, tw, 0.0))
     return (out.reshape(B, S, d).astype(x.dtype), aux["lb"], aux["z"],
-            pick.any(1).astype(jnp.int32))
+            hits, sizes)
 
 
-def moe_block(x, p, cfg, plan):
+def moe_block(x, p, cfg, plan, active=None, layer=None):
     """x: (B, S, d) sharded (batch, sp, -). Returns (out, aux) where aux
     holds the router's losses and, for a layer that holds a share of the
-    experts, ``moe_hits``: (B*S, n_experts_held) int32, which tokens each
-    held expert took."""
+    experts, ``moe_hits``: (B*S, n_experts_held) int32, which live tokens
+    each held expert took, and ``moe_group_sizes``: (n_experts_held,)
+    int32, how many (the rows its matmuls ran on).  ``active`` (B,) marks
+    the live rows of a decode step; without it every row is live.  With
+    ``layer``, a held share's expert matrices are every layer's stack
+    (``_held_block``)."""
     with jax.named_scope("moe"):
-        return _moe_block(x, p, cfg, plan)
+        return _moe_block(x, p, cfg, plan, active, layer)
 
 
-def _moe_block(x, p, cfg, plan):
+def _moe_block(x, p, cfg, plan, active=None, layer=None):
     if cfg.n_experts_held:
-        out, lb, z, hits = _held_block(x, p, cfg, plan)
+        out, lb, z, hits, sizes = _held_block(x, p, cfg, plan, active,
+                                              layer)
         return _add_shared(out, x, p, cfg), {"moe_lb": lb, "moe_z": z,
-                                             "moe_hits": hits}
+                                             "moe_hits": hits,
+                                             "moe_group_sizes": sizes}
     mesh = plan.mesh
     B, S, _d = x.shape
     batch_ax = plan._fit_dim(B, "batch")

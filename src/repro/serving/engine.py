@@ -222,16 +222,18 @@ class _Ready:
 
 class _ExpertCounts:
     """What the held experts of a model's expert layers took: each step
-    hands back the tokens routed to each held expert of each expert layer
-    (``LM.decode_step``'s ``moe_hits``, summed over the step's live
-    tokens).  Per phase (``decode``, ``prefill``):
+    hands back the live tokens routed to each held expert of each expert
+    layer (``LM.decode_step``'s ``moe_group_sizes``, the group sizes its
+    grouped matmuls ran with).  Per phase (``decode``, ``prefill``):
     ``moe_assignments``, the routed slots of every token (tokens x top_k x
     expert layers); ``moe_local_assignments``, those on held experts;
     ``moe_experts_touched``, held experts of a layer with a token, summed
-    over layers and steps; ``steps``."""
+    over layers and steps; ``moe_experts_read``, the layer-experts whose
+    weights the step read, the same number, since a held expert with no
+    token reads none; ``steps``."""
 
     KEYS = ("moe_assignments", "moe_local_assignments",
-            "moe_experts_touched", "steps")
+            "moe_experts_touched", "moe_experts_read", "steps")
 
     def __init__(self, cfg):
         self.layers = cfg.stack_sizes().get("moe", 0)
@@ -245,7 +247,9 @@ class _ExpertCounts:
             d = self._by_phase[phase]
             d["moe_assignments"] += int(tokens) * self.top_k * self.layers
             d["moe_local_assignments"] += int(counts.sum())
-            d["moe_experts_touched"] += int((counts > 0).sum())
+            read = int((counts > 0).sum())
+            d["moe_experts_touched"] += read
+            d["moe_experts_read"] += read
             d["steps"] += 1
 
     def snapshot(self) -> dict:
@@ -517,7 +521,7 @@ class DecodeNode(FFNode):
         with TraceAnnotation(SPAN_DECODE, step=step,
                              batch=int(st.active_mask.sum())):
             batch = {"token": st.cur_tok, "pos": st.pos}
-            if self._counts:      # the counts leave idle slots' rows out
+            if self._counts:      # the held experts leave idle slots out
                 batch["active"] = jnp.asarray(st.active_mask, jnp.int32)
             nt, conf, st.caches, *col = self._decode(self.params, st.caches,
                                                      batch)
@@ -663,11 +667,9 @@ class InferenceEngine:
         prefill_step = make_prefill_step(cfg, plan, cache_len, stats=counts)
         decode_step = make_decode_step(cfg, plan, cache_len, stats=counts)
 
-        def _held_counts(out, live=None):
-            hits = out[-1]["moe_hits"]              # (layers, T, held)
-            if live is not None:
-                hits = hits * live[None, :, None]
-            return hits.sum(1).reshape(-1)
+        def _held_counts(out):
+            # (layers, held), already left out of dead slots by the step
+            return out[-1]["moe_group_sizes"].reshape(-1)
 
         def _prefill(p, tokens):
             out = prefill_step(p, {"tokens": tokens})
@@ -680,7 +682,7 @@ class InferenceEngine:
             conf = jnp.max(jax.nn.softmax(logits[:, -1, :], axis=-1), -1)
             if not counts:
                 return nt, conf, caches
-            hits = _held_counts(out, batch["active"])
+            hits = _held_counts(out)
             return nt, conf, caches, jnp.concatenate([nt, hits[:, None]])
 
         self._acct = _Accounting()

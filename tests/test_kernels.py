@@ -94,6 +94,28 @@ def test_router_matches_ref(T, E, K, C, bt, rng):
                                atol=1e-6)
 
 
+@pytest.mark.parametrize("m,k,n,sizes", [
+    (24, 64, 32, [3, 0, 5, 2]),          # tail unused, an empty group
+    (24, 64, 32, [0, 0, 0, 0]),          # no group has a row
+    (256, 200, 130, [100, 0, 156]),      # every row grouped, over 2 tiles
+    (300, 1100, 96, [1, 129, 0, 7]),     # a group across a tile edge
+])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_grouped_matmul_matches_ref(m, k, n, sizes, dtype, rng):
+    """The grouped rows equal the oracle's; the tail is the caller's to
+    leave unread (the kernel does not write it)."""
+    from repro.kernels.grouped_matmul import grouped_matmul
+    k1, k2 = jax.random.split(rng)
+    lhs = _rnd(k1, m, k, dtype=dtype)
+    rhs = _rnd(k2, len(sizes), k, n, dtype=dtype, scale=k ** -0.5)
+    sizes = jnp.asarray(sizes, jnp.int32)
+    rows = int(sizes.sum())
+    got = np.asarray(grouped_matmul(lhs, rhs, sizes), np.float32)[:rows]
+    want = np.asarray(ref.grouped_matmul_ref(lhs, rhs, sizes),
+                      np.float32)[:rows]
+    np.testing.assert_allclose(got, want, rtol=TOL[dtype], atol=TOL[dtype])
+
+
 def test_router_capacity_never_exceeded(rng):
     """Property: per-expert kept count <= capacity, kept slots unique."""
     T, E, K, C = 512, 8, 2, 64

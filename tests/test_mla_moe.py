@@ -219,6 +219,108 @@ def test_skewed_routing_drops_no_token(small):
                                np.asarray(want), rtol=1e-4, atol=1e-4)
 
 
+def _every_held_expert(x2, p, cfg, live):
+    """The held layer as it was first written: every held expert on every
+    row, weighted by the router's gate; rows not ``live`` get nothing.
+    Returns (out, hits)."""
+    from repro.models.moe import _route
+    held, off = cfg.n_experts_held, cfg.expert_offset
+    _, tw, ti, _ = _route(x2, p["router"], cfg.top_k, cfg.router_score,
+                          p.get("router_bias"), cfg.routed_scale)
+    pick = (ti[:, :, None] == off + jnp.arange(held)) & live[:, None, None]
+    gate = jnp.einsum("tk,tke->te", tw, pick.astype(jnp.float32))
+    a = jnp.einsum("td,edf->etf", x2, p["wi"])
+    g = jax.nn.silu(jnp.einsum("td,edf->etf", x2, p["wg"]))
+    y = jnp.einsum("etf,efd->etd", a * g, p["wo"])
+    return (jnp.einsum("etd,te->td", y, gate),
+            pick.any(1).astype(jnp.int32))
+
+
+# (router, batch x length, live rows, correction bias added to the held
+# experts): prompts and decode steps, with dead slots, with no live row on
+# a held expert, and with every row on all of them (top-4, 4 held)
+GROUPED = {
+    "sigmoid-prefill": ("sigmoid", (1, 40), None, 0.0),
+    "softmax-prefill": ("softmax", (2, 20), None, 0.0),
+    "sigmoid-decode-dead-rows": ("sigmoid", (16, 1), 11, 0.0),
+    "softmax-decode-dead-rows": ("softmax", (16, 1), 5, 0.0),
+    "decode-no-held-expert": ("sigmoid", (16, 1), 16, -10.0),
+    "decode-all-held-experts": ("sigmoid", (16, 1), 16, 10.0),
+    "prefill-all-held-experts": ("sigmoid", (1, 40), None, 10.0),
+}
+
+
+@pytest.mark.parametrize("case", sorted(GROUPED))
+def test_grouped_held_layer_equals_every_held_expert(small, case):
+    """The held layer computes only the (token, held expert) pairs the
+    router picked, as one grouped matmul per matrix, yet equals the gate
+    times every held expert on every row, row by row; dead rows get the
+    shared expert alone, and the group sizes it returns are the pairs of
+    live rows, so no routed pair is dropped."""
+    from repro.models.moe import moe_block
+    score, (B, S), live, push = GROUPED[case]
+    _, cfg, _, _ = small
+    cfg = dataclasses.replace(cfg, router_score=score,
+                              router_bias=score == "sigmoid")
+    p = _moe_params(small)
+    off, held = cfg.expert_offset, cfg.n_experts_held
+    if score == "sigmoid":
+        p["router_bias"] = p["router_bias"].at[off:off + held].add(push)
+    else:
+        p.pop("router_bias")
+    x = jax.random.normal(jax.random.PRNGKey(6), (B, S, cfg.d_model))
+    active = None if live is None else (
+        jnp.arange(B) < live).astype(jnp.int32)
+    rows = jnp.ones((B * S,), bool) if active is None else active > 0
+    plan = single_device_plan()
+    with jax.default_matmul_precision("highest"):
+        out, aux = moe_block(x, p, cfg, plan, active)
+        shared = moe_block(x, p, dataclasses.replace(
+            cfg, expert_offset=cfg.n_experts), plan)[0]   # holds no expert
+        want, hits = _every_held_expert(x.reshape(B * S, -1), p, cfg, rows)
+    np.testing.assert_array_equal(np.asarray(aux["moe_hits"]),
+                                  np.asarray(hits))
+    sizes = np.asarray(aux["moe_group_sizes"])
+    np.testing.assert_array_equal(sizes, np.asarray(hits).sum(0))
+    if push < 0:
+        assert sizes.sum() == 0
+    if push > 0:
+        assert (sizes == int(rows.sum())).all()
+    got = np.asarray(out - shared).reshape(B * S, -1)
+    np.testing.assert_allclose(got, np.asarray(want), rtol=1e-4, atol=1e-4)
+    assert not got[~np.asarray(rows)].any()
+
+
+def test_the_engine_counts_the_experts_each_step_read(small):
+    """A decode step with dead slots returns, per expert layer, the group
+    sizes of its live rows alone (the same rows' picks as in a step where
+    every slot is live), and the engine's counter adds as read the
+    layer-experts whose group was not empty."""
+    from repro.configs import cache_specs
+    from repro.serving.engine import _ExpertCounts
+    _, cfg, _, params = small
+    plan = single_device_plan()
+    B, CL = 6, 16
+    live = np.array([1, 0, 1, 1, 0, 1], np.int32)
+    caches = jax.tree.map(lambda t: jnp.zeros(t.shape, t.dtype),
+                          cache_specs(cfg, B, CL, None))
+    batch = {"token": jax.random.randint(jax.random.PRNGKey(8), (B, 1), 0,
+                                         cfg.vocab),
+             "pos": jnp.zeros((B,), jnp.int32)}
+    decode = jax.jit(make_decode_step(cfg, plan, CL, stats=True))
+    st = decode(params, caches, dict(batch, active=jnp.asarray(live)))[-1]
+    every = decode(params, caches, batch)[-1]["moe_hits"]
+    want = (np.asarray(every) * live[None, :, None]).sum(1)
+    sizes = np.asarray(st["moe_group_sizes"])            # (layers, held)
+    np.testing.assert_array_equal(sizes, want)
+    counts = _ExpertCounts(cfg)
+    counts.add("decode", sizes.reshape(-1), int(live.sum()))
+    d = counts.snapshot()["decode"]
+    assert d["moe_experts_read"] == int((want > 0).sum()) > 0
+    assert d["moe_local_assignments"] == int(want.sum())
+    assert d["moe_experts_read"] == d["moe_experts_touched"]
+
+
 def test_the_shares_sum_to_the_uncut_layer(small):
     """The 4 shares of 4 experts each, the shared expert counted once,
     add up to the reference layer that holds all 16 experts, within

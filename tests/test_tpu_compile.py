@@ -77,6 +77,11 @@ def _ssd(q, k, v, la):
     return ssd_scan(q, k, v, la, chunk=128, interpret=False)
 
 
+def _grouped(x, w, sizes):
+    from repro.kernels.grouped_matmul import grouped_matmul
+    return grouped_matmul(x, w, sizes, interpret=False)
+
+
 def _flash(q, k, v):
     from repro.kernels.flash_attention import flash_attention
     return flash_attention(q, k, v, interpret=False)
@@ -84,7 +89,8 @@ def _flash(q, k, v):
 
 # (kernel, argument shapes and dtypes): a2a at the stream graph's widths,
 # the router at a Mixtral-like 8 experts top-2, the scan at Mamba2-like
-# head widths, attention at llama3.2-3b's 24/8 heads of 128
+# head widths, attention at llama3.2-3b's 24/8 heads of 128, the grouped
+# matmul at a Kimi-K2 decode step's held experts (64 slots x top-8 rows)
 KERNELS = {
     "a2a_fused": (_a2a, [((4096, 8), jnp.float32),
                          ((4096, 1024), jnp.float32)]),
@@ -93,6 +99,9 @@ KERNELS = {
                         ((2, 8, 2048, 128), jnp.bfloat16),
                         ((2, 8, 2048, 64), jnp.bfloat16),
                         ((2, 8, 2048), jnp.float32)]),
+    "grouped_matmul": (_grouped, [((512, 7168), jnp.bfloat16),
+                                  ((8, 7168, 2048), jnp.bfloat16),
+                                  ((8,), jnp.int32)]),
     "flash_attention": (_flash, [((1, 24, 2048, 128), jnp.bfloat16),
                                  ((1, 8, 2048, 128), jnp.bfloat16),
                                  ((1, 8, 2048, 128), jnp.bfloat16)]),
@@ -135,18 +144,23 @@ def test_llama_prefill_fits_one_v5e(llama, chip_plan, one_chip):
     assert m.argument_size_in_bytes > 7 * 10 ** 9      # all 3.6B bf16 params
 
 
-def _decode(cfg, params, chip_plan, one_chip, B, cache_len):
-    """The serving engine's decode step (per-slot positions, cache donated)
-    compiled for one chip; returns (compiled, cache structs)."""
+def _decode(cfg, params, chip_plan, one_chip, B, cache_len, active=False,
+            lower_only=False):
+    """The serving engine's decode step (per-slot positions, cache donated,
+    with ``active`` the live-slot mask the engine sends a held-expert
+    model) compiled for one chip; returns (compiled, cache structs), or
+    with ``lower_only`` (lowered, cache structs)."""
     from repro.configs import cache_specs
     from repro.runtime.steps import make_decode_step
     step = jax.jit(make_decode_step(cfg, chip_plan, cache_len),
                    donate_argnums=1)
     caches = cache_specs(cfg, B, cache_len, chip_plan)
-    compiled = step.lower(params, caches, {
-        "token": _sds((B, 1), jnp.int32, one_chip),
-        "pos": _sds((B,), jnp.int32, one_chip)}).compile()
-    return compiled, caches
+    batch = {"token": _sds((B, 1), jnp.int32, one_chip),
+             "pos": _sds((B,), jnp.int32, one_chip)}
+    if active:
+        batch["active"] = _sds((B,), jnp.int32, one_chip)
+    lowered = step.lower(params, caches, batch)
+    return (lowered if lower_only else lowered.compile()), caches
 
 
 _ARRAY = re.compile(r"^\s*(?:ROOT )?%(\S+) = \w+\[([\d,]*)\]\S* (\S+?)\((.*)")
@@ -227,6 +241,45 @@ def test_yi_stage_decode_writes_cache_rows_in_place(yi_stage, chip_plan,
     assert len(re.findall(r" while\(", hlo)) == scans
 
 
+# sha256 of the lowered StableHLO text of the dense cell's programs (Yi-34B,
+# 8 layers) from the tree before the held expert layer computed only its
+# routed rows: a change to the held layer must not move them
+YI_TEXT = {
+    "decode": ("d47bfc5acb625419df2b635ad3e33f38"
+               "98cd1d07ee30ffda430021bc03b757a8"),
+    "prefill": ("20d2d24bcd6d1fbdded1bc9f72127172"
+                "7ac9abe1a8a4ed64108f6ecd07529e56"),
+}
+
+
+def test_yi_stage_programs_lower_to_the_same_text(yi_stage, chip_plan,
+                                                  one_chip):
+    import hashlib
+    from repro.runtime.steps import make_prefill_step
+    cfg, params = yi_stage
+    decode, _ = _decode(cfg, params, chip_plan, one_chip, 16, 2560,
+                        lower_only=True)
+    prefill = jax.jit(make_prefill_step(cfg, chip_plan, 2560)).lower(
+        params, {"tokens": _sds((1, 512), jnp.int32, one_chip)})
+    got = {name: hashlib.sha256(low.as_text().encode()).hexdigest()
+           for name, low in (("decode", decode), ("prefill", prefill))}
+    assert got == YI_TEXT
+
+
+@pytest.fixture
+def on_chip(monkeypatch):
+    """The program's Pallas kernels resolved as on the chip (Mosaic, not
+    the interpreter), for a compile made here, where JAX's backend is the
+    CPU."""
+    from repro.kernels import backend
+    monkeypatch.setattr(backend, "on_tpu", lambda: True)
+
+
+def _op_name(line):
+    m = re.search(r'op_name="([^"]*)"', line)
+    return m.group(1) if m else ""
+
+
 @pytest.fixture(scope="module")
 def kimi_share(chip_plan):
     """Kimi-K2 at published widths as the benchmark cuts it: the dense
@@ -242,15 +295,17 @@ def kimi_share(chip_plan):
 
 
 def test_kimi_share_decode_writes_latent_rows_in_place(kimi_share, chip_plan,
-                                                       one_chip):
+                                                       one_chip, on_chip):
     """64 slots of 3,584: the 9.6 GB of weights and the 2.6 GB latent cache
     fit one chip, the step writes its new latent rows into the donated
     cache and reads each layer where it lies, so its scratch stays below
     one layer of the cache, no copy of the cache or of a layer is made,
     and the MoE layer scan is the only loop (the one dense layer's is
-    unrolled)."""
+    unrolled) but for the grouped matmul's group bookkeeping inside the
+    expert layer (megablox's ``searchsorted``)."""
     cfg, params = kimi_share
-    compiled, caches = _decode(cfg, params, chip_plan, one_chip, 64, 3584)
+    compiled, caches = _decode(cfg, params, chip_plan, one_chip, 64, 3584,
+                               active=True)
     m = _fits(compiled)
     assert m.argument_size_in_bytes > 12 * 10 ** 9
     cache_bytes = sum(c.size * c.dtype.itemsize
@@ -259,11 +314,34 @@ def test_kimi_share_decode_writes_latent_rows_in_place(kimi_share, chip_plan,
     assert m.temp_size_in_bytes < cache_bytes / cfg.n_layers, m
     hlo = compiled.as_text()
     assert _cache_sized(hlo, caches) == []
-    assert len(re.findall(r" while\(", hlo)) == 1
+    loops = [_op_name(line) for line in hlo.splitlines()
+             if " while(" in line]
+    assert [n for n in loops if "/moe/" not in n] == [
+        "jit(decode_step)/while"], loops
+    assert all("/moe/jit(gmm)/" in n for n in loops if "/moe/" in n), loops
+
+
+def test_kimi_share_decode_runs_held_experts_as_grouped_matmuls(
+        kimi_share, chip_plan, one_chip, on_chip):
+    """The held experts' three matrices run as three grouped-matmul
+    kernels (``kernels/grouped_matmul.py``) in the expert layers' scan,
+    each under ``jax.named_scope("moe")``, and no XLA conditional sits
+    under "moe": its own event and its inner operations would both be
+    counted as the scope's time by the benchmark's scope reader."""
+    cfg, params = kimi_share
+    compiled, _ = _decode(cfg, params, chip_plan, one_chip, 64, 3584,
+                          active=True)
+    lines = compiled.as_text().splitlines()
+    grouped = [_op_name(line) for line in lines
+               if "custom-call(" in line and "tpu_custom_call" in line]
+    assert len(grouped) == 3, grouped
+    assert all("/moe/" in n and "gmm" in n for n in grouped), grouped
+    assert not [line for line in lines
+                if " conditional(" in line and "/moe/" in _op_name(line)]
 
 
 def test_kimi_share_longest_prefill_fits_one_v5e(kimi_share, chip_plan,
-                                                 one_chip):
+                                                 one_chip, on_chip):
     from repro.runtime.steps import make_prefill_step
     cfg, params = kimi_share
     step = jax.jit(make_prefill_step(cfg, chip_plan, 3584))
