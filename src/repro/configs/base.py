@@ -1,28 +1,17 @@
-"""Architecture config schema + assigned input shapes.
+"""Architecture config schema.
 
 Every assigned architecture is a ``Config`` in its own module
 (``configs/<id>.py``) selectable via ``--arch <id>`` (configs/registry.py).
-``input_specs`` builds ShapeDtypeStruct stand-ins (weak-type-correct,
-shardable, no allocation) for every (arch x shape) dry-run cell.
+``cache_specs`` builds ShapeDtypeStruct stand-ins (shardable, no
+allocation) for a model's decode caches.
 """
 
 from __future__ import annotations
 
 import dataclasses
-import math
-from typing import Any, Dict, List, Optional, Tuple
+from typing import Dict, List, Optional, Tuple
 
 import jax
-import jax.numpy as jnp
-
-
-# the assigned shape grid (LM transformer shapes) -----------------------------
-SHAPES: Dict[str, Dict[str, Any]] = {
-    "train_4k":    {"seq": 4096,   "batch": 256, "mode": "train"},
-    "prefill_32k": {"seq": 32768,  "batch": 32,  "mode": "prefill"},
-    "decode_32k":  {"seq": 32768,  "batch": 128, "mode": "decode"},
-    "long_500k":   {"seq": 524288, "batch": 1,   "mode": "decode"},
-}
 
 
 @dataclasses.dataclass
@@ -105,7 +94,6 @@ class Config:
     n_microbatches: int = 1
     q_block: int = 2048
     kv_block: int = 2048
-    use_pallas: bool = False         # Pallas kernels (TPU); XLA fallback here
 
     def __post_init__(self):
         if self.head_dim == 0:
@@ -135,59 +123,11 @@ class Config:
             out[kind] = out.get(kind, 0) + (1 if kind == "shared_attn" else count)
         return out
 
-    @property
-    def subquadratic(self) -> bool:
-        return (self.family in ("ssm", "hybrid")
-                or (self.attn_kind == "swa"))
-
-    def supports(self, shape_name: str) -> bool:
-        if shape_name == "long_500k":
-            return self.subquadratic
-        return True
-
-    def skip_reason(self, shape_name: str) -> str:
-        if shape_name == "long_500k" and not self.subquadratic:
-            return ("pure full-attention arch: 512k decode needs "
-                    "sub-quadratic attention (see DESIGN.md)")
-        return ""
-
-    # -- parameter counts for MODEL_FLOPS -------------------------------------
+    # -- parameter count --------------------------------------------------------
     def n_params(self) -> int:
         from ..models.lm import LM
         from ..models.params import count_params
         return count_params(LM(self).param_defs())
-
-    def n_params_active(self) -> int:
-        """Active params per token (MoE: routed top-k + shared only)."""
-        if self.family != "moe":
-            return self.n_params()
-        from ..models.lm import LM
-        from ..models.params import count_params, is_def
-        defs = LM(self).param_defs()
-        total = 0
-        for path, leaf in jax.tree_util.tree_flatten_with_path(
-                defs, is_leaf=is_def)[0]:
-            keys = [getattr(k, "key", getattr(k, "name", "")) for k in path]
-            n = math.prod(leaf.shape)
-            if any(k in ("wi", "wg", "wo") for k in keys) and \
-                    "moe" in keys and "shared" not in keys:
-                n = n * self.top_k // self.n_experts
-            total += n
-        return total
-
-    def model_flops(self, shape_name: str) -> float:
-        """MODEL_FLOPS = 6*N*D (train) / 2*N*D (inference forward), with
-        N = active params, D = tokens processed by the step."""
-        sh = SHAPES[shape_name]
-        n = self.n_params_active()
-        if sh["mode"] == "train":
-            tokens = sh["seq"] * sh["batch"]
-            return 6.0 * n * tokens
-        if sh["mode"] == "prefill":
-            tokens = sh["seq"] * sh["batch"]
-            return 2.0 * n * tokens
-        tokens = sh["batch"]          # one new token per sequence
-        return 2.0 * n * tokens
 
     # -- reduced config for CPU smoke tests ------------------------------------
     def reduced(self) -> "Config":
@@ -241,42 +181,6 @@ def _sds(shape, dtype, plan=None, axes=None):
         return jax.ShapeDtypeStruct(shape, dtype)
     return jax.ShapeDtypeStruct(shape, dtype,
                                 sharding=plan.sharding_for(axes, shape))
-
-
-def batch_specs(cfg: Config, shape_name: str, plan=None, batch=None, seq=None):
-    """Model-input stand-ins for a shape cell (dry-run pattern: weak-type
-    correct, shardable, zero allocation).  Frontends are stubs: [audio]/[vlm]
-    get precomputed frame/patch embeddings."""
-    sh = SHAPES[shape_name]
-    B = batch if batch is not None else sh["batch"]
-    S = seq if seq is not None else sh["seq"]
-    mode = sh["mode"]
-    i32, bf16 = jnp.int32, jnp.bfloat16
-    bax = ("batch",)
-
-    if mode in ("train", "prefill"):
-        if cfg.family == "encdec":
-            dec = max(32, S // 8)
-            return {"frames": _sds((B, S, cfg.d_model), bf16, plan,
-                                   ("batch", None, None)),
-                    "tokens": _sds((B, dec), i32, plan, ("batch", None))}
-        out = {"tokens": _sds((B, S), i32, plan, ("batch", None))}
-        if cfg.family == "vlm":
-            out["embeds"] = _sds((B, S, cfg.d_model), bf16, plan,
-                                 ("batch", None, None))
-            out["mrope_positions"] = _sds((3, B, S), i32, plan,
-                                          (None, "batch", None))
-        return out
-
-    # decode: one new token against a cache of length S
-    out = {"token": _sds((B, 1), i32, plan, ("batch", None)),
-           "pos": _sds((), i32, plan, ())}
-    if cfg.family == "vlm":
-        out["embeds"] = _sds((B, 1, cfg.d_model), bf16, plan,
-                             ("batch", None, None))
-        out["mrope_positions"] = _sds((3, B, 1), i32, plan,
-                                      (None, "batch", None))
-    return out
 
 
 def cache_specs(cfg: Config, B: int, S: int, plan=None):

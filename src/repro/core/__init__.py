@@ -113,16 +113,12 @@ hop, or a ``feedback_scan`` tail.  This is the paper's layered lesson
 applied to the device tier: composition must collapse into cheap
 communication, so N composed stages cost one dispatch, not N host
 round-trips.  ``compile(fuse=False)`` restores the one-program-per-stage
-emit (per-stage observability, A/B benchmarks —
-``benchmarks/bench_core.py``'s ``device_fusion_speedup`` gates the win in
-CI).  Jitted segments are cached across ``compile()`` calls keyed by
-fused-stage identity, so the adaptive Supervisor's re-place path reuses
-traced programs instead of retracing; ``place`` amortizes the calibrated
-``device_dispatch_s`` over each candidate run (plus the measured
-``fused_segment_s`` marginal), which is what lets fused device placement
-win at much smaller stage grain, and kernel tile sizes come from
-``benchmarks/roofline.py --autotune`` winners persisted in the
-``perf_model`` cache.
+emit (per-stage observability, A/B comparisons).  Jitted segments are
+cached across ``compile()`` calls keyed by fused-stage identity, so the
+adaptive Supervisor's re-place path reuses traced programs instead of
+retracing; ``place`` amortizes the calibrated ``device_dispatch_s`` over
+each candidate run (plus the measured ``fused_segment_s`` marginal), which
+is what lets fused device placement win at much smaller stage grain.
 
 The overlapped device boundary: fusion made each host<->device hop cost one
 dispatch; overlap makes those dispatches *asynchronous and software-
@@ -135,13 +131,10 @@ completes (``copy_to_host_async``), while i computes; nothing calls
 preserves exact input order.  Three ``CompileConfig`` knobs govern it —
 ``overlap`` (default True), ``microbatch`` (the boundary's stacking unit,
 ``device_batch``'s modern name), and ``inflight`` (the window depth,
-defaulting to the ``device_overlap:window`` winner the
-``roofline.py --autotune`` depth sweep persists in the ``perf_model``
-cache).  ``overlap=False`` (or ``inflight=1``) restores the strictly
+default 2).  ``overlap=False`` (or ``inflight=1``) restores the strictly
 synchronous put -> compute -> copy-out boundary and is byte-identical —
 the same jitted programs see the same stacked inputs; only the
-synchronization point moves — which is what the ``device_overlap_speedup``
-bench gates in CI.  ``place`` costs a fused device run at
+synchronization point moves.  ``place`` costs a fused device run at
 ``max(transfer, compute)`` through the calibrated h2d/d2h bandwidths and
 overlap efficiency (calib cache v5), boundary nodes publish
 submit/drain/stall stats through a ``boundary_tunable``

@@ -110,8 +110,7 @@ class CompileConfig:
     benchmarks, parity tests); results are byte-identical either way — only
     the synchronization point moves.  ``microbatch=`` overrides the
     boundary's stacking depth (default: ``device_batch`` heuristic, 8x the
-    mesh axis), ``inflight=`` the window depth K (default: the roofline
-    autotuner's ``device_overlap:window`` sweep winner, else 2).  Feedback
+    mesh axis), ``inflight=`` the window depth K (default 2).  Feedback
     (``wrap_around``) graphs always compile the synchronous boundary: items
     circulate one at a time, and a window holding results back would
     deadlock the loop."""
@@ -1308,8 +1307,7 @@ def emit(graph: FFGraph, plan: Any = None, *, capacity: int = 512,
         if graph._wrap:
             overlap = False
         if inflight is None:
-            rec = pm.lookup_autotuned("device_overlap:window")
-            inflight = int(rec.get("inflight", 2)) if rec else 2
+            inflight = 2
         new_stages: List[Any] = []
         for entry, p in fuse_device_segments(stages, placements,
                                              enable=fuse):

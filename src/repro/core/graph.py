@@ -1169,7 +1169,6 @@ class DeviceRunner(Runner):
                  fuse: bool = True, overlap: bool = True,
                  microbatch: Optional[int] = None,
                  inflight: Optional[int] = None):
-        from . import perf_model as pm
         from .compiler import _top_stages, make_device_batched
         from .fuse import jit_segment, segment_key
         self._t0 = self._t1 = 0.0
@@ -1183,8 +1182,7 @@ class DeviceRunner(Runner):
         # chunking would re-trace the scan per chunk shape for no benefit
         self._microbatch = None if graph._wrap else microbatch
         if inflight is None:
-            rec = pm.lookup_autotuned("device_overlap:window")
-            inflight = int(rec.get("inflight", 2)) if rec else 2
+            inflight = 2
         self._inflight = max(1, int(inflight)) if overlap else 1
         # boundary accounting (cumulative seconds; under _stats_lock)
         self._b_h2d = 0.0      # host stack + device transfer submit

@@ -6,8 +6,7 @@ The paper's algebra:
   * pipeline: service time T_S = max_i T_Si; speedup = sum T_Si / max T_Si.
 
 We reuse exactly that algebra to pick pipeline microbatch counts and farm
-widths, and extend it with a three-term roofline (compute / HBM / ICI) used by
-benchmarks/roofline.py and the §Perf hillclimb.
+widths, and extend it with a three-term roofline (compute / HBM / ICI).
 """
 
 from __future__ import annotations
@@ -244,10 +243,9 @@ DEFAULT_CALIBRATION = HostCalibration(
     h2d_bw_gbs=8.0, d2h_bw_gbs=8.0, overlap_eff=0.5, source="default")
 
 # version 5: h2d_bw_gbs/d2h_bw_gbs + overlap_eff (the overlapped device
-# boundary); version 4: fused_segment_s (device-segment fusion) + the
-# autotune table; version 3: shm_batched_hop_s + arena_bw_gbs joined (the
-# batched uSPSC transport); version 2 added net_hop_s — older caches must
-# miss cleanly
+# boundary); version 4: fused_segment_s (device-segment fusion); version 3:
+# shm_batched_hop_s + arena_bw_gbs joined (the batched uSPSC transport);
+# version 2 added net_hop_s — older caches must miss cleanly
 _CALIB_VERSION = 5
 _calibration: Optional[HostCalibration] = None
 
@@ -256,21 +254,18 @@ def cache_key() -> Dict[str, Any]:
     """What every cached record was measured on: the cache format version,
     the host's core count and the JAX backend's first device.  A record
     whose key differs is a miss, so constants measured on one backend (the
-    CPU, say) never steer placement or tiles on another (a TPU)."""
+    CPU, say) never steer placement on another (a TPU)."""
     import jax
     dev = jax.devices()[0]
     return {"version": _CALIB_VERSION, "cpu_count": os.cpu_count(),
             "platform": dev.platform, "device_kind": dev.device_kind}
 
 
-def _key_matches(d: Any, *, cpu: bool = True) -> bool:
-    """Does a loaded cache dict carry this process's :func:`cache_key`?
-    ``cpu=False`` ignores the core count (tile winners depend on the
-    accelerator backend and shape only)."""
+def _key_matches(d: Any) -> bool:
+    """Does a loaded cache dict carry this process's :func:`cache_key`?"""
     if not isinstance(d, dict):
         return False
-    return all(d.get(k) == v for k, v in cache_key().items()
-               if cpu or k != "cpu_count")
+    return all(d.get(k) == v for k, v in cache_key().items())
 
 
 def _calib_cache_path() -> str:
@@ -795,16 +790,15 @@ def reset_observed() -> None:
 
 
 def _save_cache_tables(what: str = "observed costs") -> None:
-    """Persist calibration + observed + autotune tables into the one cache
-    file; a read-only location degrades to in-memory with a warning."""
+    """Persist the calibration + observed tables into the one cache file; a
+    read-only location degrades to in-memory with a warning."""
     path = _calib_cache_path()
     c = get_calibration(measure=False)
     try:
         os.makedirs(os.path.dirname(path), exist_ok=True)
         with open(path, "w") as f:
             json.dump({**cache_key(), **c.as_dict(),
-                       "observed": _load_observed(),
-                       "autotune": _load_autotune()}, f)
+                       "observed": _load_observed()}, f)
     except OSError as e:
         warnings.warn(
             f"perf_model: calibration cache {path!r} is not writable ({e}); "
@@ -814,71 +808,6 @@ def _save_cache_tables(what: str = "observed costs") -> None:
 
 def _save_observed() -> None:
     _save_cache_tables("observed costs")
-
-
-# --------------------------------------------------------------------------
-# Tile autotuning — ``benchmarks/roofline.py --autotune`` winners
-# --------------------------------------------------------------------------
-# The sweep times kernel tile candidates (``block_t`` of the fused a2a hop
-# and the router, ``chunk`` of the SSD scan) per shape on THIS backend and
-# records the winners here, keyed ``"<kernel>:T<T>:E<E>:D<D>"``.  Kernels
-# consult :func:`lookup_autotuned` when called without an explicit tile, so
-# a pre-warmed cache (CI warms it alongside the calibration) changes real
-# dispatch shapes without any pytest worker ever paying for the sweep; an
-# absent record is simply a heuristic default, never a trigger to sweep.
-# The table lives inside the same calibration.json (same REPRO_FF_CACHE
-# resolution, same read-only degradation).
-
-_autotune: Optional[Dict[str, dict]] = None
-
-
-def _load_autotune() -> Dict[str, dict]:
-    global _autotune
-    if _autotune is None:
-        _autotune = {}
-        try:
-            with open(_calib_cache_path()) as f:
-                d = json.load(f)
-            at = d.get("autotune")
-            # unlike the observed table, tile winners do not gate on
-            # cpu_count: they depend on the accelerator backend and shape
-            if isinstance(at, dict) and _key_matches(d, cpu=False):
-                _autotune = {str(k): dict(v) for k, v in at.items()
-                             if isinstance(v, dict)}
-        except (OSError, ValueError, TypeError):
-            pass
-    return _autotune
-
-
-def lookup_autotuned(key: Optional[str]) -> Optional[dict]:
-    """The autotuned record for a kernel/shape key (e.g.
-    ``"a2a_fused:T256:E4:D64"``), or None — callers fall back to their
-    heuristic tile and never sweep."""
-    if not key:
-        return None
-    rec = _load_autotune().get(key)
-    return dict(rec) if rec else None
-
-
-def record_autotuned(entries: Dict[str, dict], write: bool = True) -> int:
-    """Merge sweep winners into the autotune table; ``write=True`` persists
-    them (with the calibration + observed tables) into the on-disk cache.
-    Returns the number of records absorbed."""
-    table = _load_autotune()
-    n = 0
-    for k, v in entries.items():
-        if isinstance(v, dict):
-            table[str(k)] = dict(v)
-            n += 1
-    if write and n:
-        _save_cache_tables("autotune results")
-    return n
-
-
-def reset_autotuned() -> None:
-    """Drop the in-memory autotune table (tests)."""
-    global _autotune
-    _autotune = None
 
 
 def _stat_records(x, out: list) -> None:
@@ -957,25 +886,3 @@ def observe(stats: dict, alpha: float = 0.25, write: bool = False) -> int:
     if write and absorbed:
         _save_observed()
     return absorbed
-
-
-# ring-model per-chip traffic for each collective kind -----------------------
-def collective_link_bytes(kind: str, operand_bytes: float, group_size: int) -> float:
-    """Per-chip bytes that traverse links for one collective, ring algorithm.
-    ``operand_bytes`` is the per-device operand (post-SPMD HLO shapes are
-    already per-device)."""
-    n = max(group_size, 1)
-    if n == 1:
-        return 0.0
-    if kind == "all-reduce":
-        return 2.0 * operand_bytes * (n - 1) / n
-    if kind in ("all-gather",):
-        # operand is the local shard; each chip receives (n-1) shards
-        return operand_bytes * (n - 1)
-    if kind in ("reduce-scatter",):
-        return operand_bytes * (n - 1) / n
-    if kind in ("all-to-all",):
-        return operand_bytes * (n - 1) / n
-    if kind in ("collective-permute", "collective-permute-start"):
-        return operand_bytes
-    return operand_bytes

@@ -93,18 +93,10 @@ def exclusive_rank(onehot):
         preferred_element_type=jnp.float32).astype(jnp.int32)
 
 
-def _pick_block(T: int, block_t: Optional[int], E: int, Din: int) -> int:
-    """Requested block, else the autotuned winner for this shape, else a
-    heuristic — snapped down to a multiple of 8 that divides T (the TPU's
-    sublane tiling), or T itself when no such divisor fits."""
-    if block_t is None:
-        try:  # lazy: kernels must stay importable without the core package
-            from ..core import perf_model as pm
-            rec = pm.lookup_autotuned(f"a2a_fused:T{T}:E{E}:D{Din}")
-            if rec:
-                block_t = int(rec["block_t"])
-        except Exception:   # noqa: BLE001 - tuning is advisory, never fatal
-            block_t = None
+def _pick_block(T: int, block_t: Optional[int]) -> int:
+    """Requested block, else 128 — snapped down to a multiple of 8 that
+    divides T (the TPU's sublane tiling), or T itself when no such divisor
+    fits."""
     if block_t is None:
         block_t = 128
     bt = min(block_t, T) // 8 * 8
@@ -133,7 +125,7 @@ def a2a_fused(logits, xs, expert_fns: Sequence[Callable], capacity: int, *,
                          f"{[(o.shape, str(o.dtype)) for o in outs]}")
     per_out = outs[0]
     Dout = int(math.prod(per_out.shape)) if per_out.shape else 1
-    bt = _pick_block(T, block_t, E, Din)
+    bt = _pick_block(T, block_t)
     nt = T // bt
 
     kernel = functools.partial(

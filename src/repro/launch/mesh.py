@@ -1,8 +1,7 @@
-"""Production mesh construction.
+"""Mesh construction.
 
-A FUNCTION (not module-level state) so importing this module never touches
-jax device initialization.  The dry-run entrypoint (dryrun.py) sets
-``XLA_FLAGS=--xla_force_host_platform_device_count=512`` before any import.
+FUNCTIONS (not module-level state) so importing this module never touches
+jax device initialization.
 """
 
 from __future__ import annotations
@@ -12,12 +11,6 @@ import jax
 
 def _mesh_kwargs(axes):
     return {"axis_types": (jax.sharding.AxisType.Auto,) * len(axes)}
-
-
-def make_production_mesh(*, multi_pod: bool = False):
-    shape = (2, 16, 16) if multi_pod else (16, 16)
-    axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **_mesh_kwargs(axes))
 
 
 def make_mesh(shape, axes):

@@ -13,9 +13,9 @@
     (score/value contractions become partial-sum collectives — the
     farm-with-collector skeleton, flash-decoding).
 
-Both paths use a blocked streaming softmax (never materializing (S, S)),
-mirroring the Pallas kernel (kernels/flash_attention.py) the TPU build uses;
-this XLA path is the dry-run / CPU fallback (``config.use_pallas=False``).
+Both paths use a blocked streaming softmax (never materializing (S, S)).
+This XLA path is the only attention path on every backend: the Pallas
+kernel (kernels/flash_attention.py) is tested beside it but not wired in.
 The q-block x kv-block loops are *unrolled*, so blocks above the causal
 diagonal / outside the SWA window are skipped at trace time — both the
 FLOPs and the HLO cost analysis reflect kernel-like work.

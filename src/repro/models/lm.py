@@ -19,14 +19,11 @@ with stub frontends (precomputed frame/patch embeddings per the assignment).
 
 Sharding: only logical axes (core/plan.py).  Embedding and cross-entropy are
 vocab-parallel (Megatron-style shard_map) so full logits are never
-materialized.  The layer scans are the *only* ``while`` loops in any step
-function — launch/dryrun.py relies on this for exact loop-corrected cost
-accounting — but for one inside an expert layer that holds a share of the
-experts (the grouped matmul's group bookkeeping).  Decode keeps it so:
+materialized.  Decode carries each stacked cache through the layer scan:
 each layer stores its new K/V rows with one batched scatter into the
 carried cache, which the TPU compiler emits as an in-place fusion, not a
-loop (tests/test_tpu_compile.py counts the loops of a compiled decode
-step).
+copy or a loop (tests/test_tpu_compile.py guards this on a compiled
+decode step).
 """
 
 from __future__ import annotations
@@ -684,19 +681,3 @@ class LM:
             out[kind] = _cache_struct(kind, cfg, B,
                                       S_eff if kind != "dec" else S_eff, L)
         return out
-
-    # -- dry-run metadata -----------------------------------------------------------
-    def loop_specs(self, mode: str):
-        """[(kind, trips, scan_instances)] for cost correction."""
-        cfg = self.cfg
-        segs = cfg.segments
-        if cfg.family == "encdec" and mode == "decode":
-            segs = [("dec", cfg.dec_layers)]
-        agg: Dict[str, list] = {}
-        for kind, count in segs:
-            if kind == "shared_attn":
-                continue  # unrolled, counted raw
-            agg.setdefault(kind, [0, 0])
-            agg[kind][0] += count
-            agg[kind][1] += 1
-        return [(k, v[0], v[1]) for k, v in agg.items()]

@@ -6,9 +6,6 @@ The train step is the device lowering of the outer farm skeleton:
   collector = gradient reduction (reduce-scatter over data via FSDP
               shardings; all-reduce over pod, optionally int8-EF-compressed)
   feedback  = the optimizer update + grad-accumulation loop (wrap_around)
-
-Only the layer scans (and the optional grad-accumulation scan) introduce
-``while`` loops — launch/dryrun.py depends on this (DESIGN.md §7).
 """
 
 from __future__ import annotations

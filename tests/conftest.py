@@ -1,5 +1,5 @@
-# Tests run on the single real CPU device (the dry-run's 512 fake devices
-# are set ONLY inside launch/dryrun.py / subprocess tests, never here).
+# Tests run on the single real CPU device (fake multi-device hosts are set
+# up ONLY inside subprocess tests, never here).
 import os
 import sys
 import pathlib

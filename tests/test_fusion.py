@@ -295,7 +295,7 @@ def test_a2a_fused_block_is_sublane_tiled(T, block_t, want):
     """A token block the TPU accepts: a multiple of 8 that divides T, or T
     itself (T=200 used to give 100)."""
     from repro.kernels.a2a_fused import _pick_block
-    assert _pick_block(T, block_t, 2, 8) == want
+    assert _pick_block(T, block_t) == want
 
 
 @pytest.mark.kernels

@@ -130,9 +130,18 @@ def test_router_capacity_never_exceeded(rng):
     assert keep.sum() > 0
 
 
+@pytest.mark.parametrize("T,want", [(64, 64), (100, 100), (128, 128),
+                                    (200, 40), (256, 128), (1000, 40)])
+def test_pick_block_default(T, want):
+    """With no block asked for, the a2a kernel's token block is 128 snapped
+    down to a multiple of 8 that divides T, else T."""
+    from repro.kernels.a2a_fused import _pick_block
+    assert _pick_block(T, None) == want
+
+
 def test_flash_attention_in_model_path(plan, rng):
-    """cfg.use_pallas integration: attention block output with the kernel
-    equals the XLA streaming path."""
+    """The flash-attention kernel on the attention block's projections
+    equals the block's XLA streaming path."""
     from repro.configs import get
     from repro.models import attention as A
     from repro.models.params import init_params

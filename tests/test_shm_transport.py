@@ -400,24 +400,39 @@ def test_calibration_cache_roundtrips_batched_constants(tmp_path, monkeypatch):
     assert pm._load_cached_calibration() is None
 
 
-def test_autotune_records_are_keyed_by_device(tmp_path, monkeypatch):
-    """Tile winners persist with the backend device in their key: a table
-    tuned on another device is a miss, never a tile to use here."""
+def test_stale_autotune_cache_is_ignored(tmp_path, monkeypatch, plan):
+    """A calibration cache that an older tree wrote with an ``"autotune"``
+    table of tile and window winners still loads its calibration, and
+    nothing reads the table: the a2a block and the device boundary's
+    in-flight window keep their defaults."""
+    import dataclasses
     import json
-    monkeypatch.setenv("REPRO_FF_CALIB_CACHE", str(tmp_path / "calib.json"))
-    pm.reset_autotuned()
+    from repro.core.compiler import CompileConfig, _DeviceStageNode
+    from repro.kernels.a2a_fused import _pick_block
+    monkeypatch.delenv("REPRO_FF_CALIB_CACHE", raising=False)
+    monkeypatch.setenv("REPRO_FF_CACHE", str(tmp_path))
+    c = dataclasses.replace(pm.DEFAULT_CALIBRATION, queue_hop_s=7e-6)
+    with open(tmp_path / "calibration.json", "w") as f:
+        json.dump({**pm.cache_key(), **c.as_dict(), "observed": {},
+                   "autotune": {"a2a_fused:T256:E2:D8": {"block_t": 16},
+                                "device_overlap:window": {"inflight": 5}}},
+                  f)
+    pm.reset_calibration()
     try:
-        assert pm.record_autotuned({"a2a_fused:T64:E2:D8": {"block_t": 16}})
-        pm.reset_autotuned()
-        assert pm.lookup_autotuned("a2a_fused:T64:E2:D8") == {"block_t": 16}
-        with open(tmp_path / "calib.json") as f:
-            d = json.load(f)
-        assert {k: d[k] for k in ("platform", "device_kind")} == {
-            k: pm.cache_key()[k] for k in ("platform", "device_kind")}
-        d["device_kind"] = "another device"
-        with open(tmp_path / "calib.json", "w") as f:
-            json.dump(d, f)
-        pm.reset_autotuned()
-        assert pm.lookup_autotuned("a2a_fused:T64:E2:D8") is None
+        assert _pick_block(256, None) == 128
+        r = pipeline(lambda x: float(x), lambda x: x + 1.0).compile(
+            config=CompileConfig(plan=plan, inflight=None, normalize=False,
+                                 placements={0: "host", 1: "device"}))
+        node = [s for s in r._skel._stages
+                if isinstance(s, _DeviceStageNode)][0]
+        assert node._inflight == 2
+        out = r.run([np.float32(i) for i in range(5)])
+        assert [float(np.asarray(y)) for y in out] == [
+            i + 1.0 for i in range(5)]
+        d = pipeline(lambda x: x + 1.0).compile(
+            config=CompileConfig(plan=plan, mode="device", inflight=None))
+        assert d._inflight == 2
+        loaded = pm.get_calibration(measure=False)
+        assert loaded.queue_hop_s == 7e-6 and loaded.source == "cached"
     finally:
-        pm.reset_autotuned()
+        pm.reset_calibration()

@@ -227,7 +227,6 @@ def test_yi_stage_decode_writes_cache_rows_in_place(yi_stage, chip_plan,
     1.34 GB cache and reads each layer where it lies, so its scratch stays
     below one layer's K+V, no copy of the cache or of a layer is made, and
     the layer scan is the only loop."""
-    from repro.models.lm import LM
     cfg, params = yi_stage
     compiled, caches = _decode(cfg, params, chip_plan, one_chip, 16, 2560)
     m = _fits(compiled)
@@ -237,7 +236,7 @@ def test_yi_stage_decode_writes_cache_rows_in_place(yi_stage, chip_plan,
     assert m.temp_size_in_bytes < cache_bytes / cfg.n_layers, m
     hlo = compiled.as_text()
     assert _cache_sized(hlo, caches) == []
-    scans = sum(n for _, _, n in LM(cfg).loop_specs("decode"))
+    scans = sum(kind != "shared_attn" for kind, _ in cfg.segments)
     assert len(re.findall(r" while\(", hlo)) == scans
 
 
